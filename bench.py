@@ -12,7 +12,7 @@ size (round 1's "memory cliff"), 2^23 int64 (the lexicographic-planes path),
 the TeraSort kv local phase (two-level key + 90 B payload permute, rec/s),
 the post-shuffle merge comparison (block_merge_runs vs full re-sort vs the
 jnp bitonic tree at the SPMD shape), the BASELINE config ladder (5 configs),
-and a phase split of one SPMD sort.  Every line is a TPU measurement: a run
+and the device-resident sort with on-device validation.  Every line is a TPU measurement: a run
 that finds no TPU exits non-zero instead of measuring something else.
 
 Timing methodology (r4 — reconciling the r3 chain-vs-slope gap): a single
@@ -67,6 +67,8 @@ BENCH_SCHEMA_FIELD_TYPES = {
     "fixed_overhead_ms_per_dispatch": "num",
     "validated_ok": "bool",
     "bit_identical": "bool",
+    # Phase-split rows of earlier suites (the in-tree BENCH_r*.jsonl carry
+    # them; the program's ``partition.*``/``assemble.*`` steps time them now):
     "host_fraction": "num",
     "host_fraction_link": "num",
     "host_fraction_code": "num",
@@ -680,84 +682,6 @@ def _emit_slope(name: str, n_items: int, unit: str, sort_fn, x, c1, c2, reps,
     return f, per, fixed, chained
 
 
-def _probe_transfer(reps: int, nbytes: int = 32 << 20) -> dict | None:
-    """Measure the host<->device link: warm H2D/D2H MB/s + small-RTT.
-
-    The r5 review's scratch probe, productized (VERDICT r5 next #4): one
-    32 MB buffer rides device_put (H2D) and np.asarray (D2H) ``reps`` times
-    warm — min over reps, the suite's one-sided-jitter doctrine — and an
-    8-int32 round-trip measures the fixed per-dispatch RTT.  Bulk timings
-    subtract the RTT floor so bandwidth and latency don't double-count.
-    Emits one ``transfer_probe_link`` artifact line; returns the figures
-    for the phase-split rows' `expected_transfer_s` derivation (None if the
-    probe itself failed — the e2e rows then carry no decomposition rather
-    than a wrong one).
-    """
-    import jax
-
-    try:
-        host = np.random.default_rng(7).integers(
-            0, 255, nbytes, dtype=np.uint8
-        )
-        tiny = np.zeros(8, np.int32)
-        d = jax.device_put(host)
-        np.asarray(d[-8:])  # warm both directions + compile the slice
-        np.asarray(jax.device_put(tiny)[:1])
-        rtts = []
-        for _ in range(max(reps, 3)):
-            t0 = time.perf_counter()
-            np.asarray(jax.device_put(tiny)[:1])
-            rtts.append(time.perf_counter() - t0)
-        rtt = float(min(rtts))
-        h2d = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            d = jax.device_put(host)
-            np.asarray(d[-8:])  # tiny fetch = completion barrier
-            h2d.append(time.perf_counter() - t0)
-        d2h = []
-        for _ in range(reps):
-            # Fresh device array each rep: jax caches the host copy on the
-            # Array after the first full np.asarray, and a cached read would
-            # measure memcpy, not the link.  The re-put + barrier sit
-            # OUTSIDE the timed region.
-            dd = jax.device_put(host)
-            np.asarray(dd[-8:])
-            t0 = time.perf_counter()
-            np.asarray(dd)
-            d2h.append(time.perf_counter() - t0)
-        # 100 us floor: where a direction is effectively free (CPU memcpy),
-        # report a ~"nbytes / 100 us" ceiling, not an absurd 1e15 B/s.
-        h2d_s = max(float(min(h2d)) - rtt, 1e-4)
-        d2h_s = max(float(min(d2h)) - rtt, 1e-4)
-        out = {
-            "h2d_bytes_per_s": nbytes / h2d_s,
-            "d2h_bytes_per_s": nbytes / d2h_s,
-            "rtt_s": rtt,
-        }
-        _emit_line(
-            {
-                "metric": "transfer_probe_link",
-                "value": round(min(out["h2d_bytes_per_s"],
-                                   out["d2h_bytes_per_s"]) / 1e6, 1),
-                "unit": "MB/s",
-                "h2d_mb_per_s": round(out["h2d_bytes_per_s"] / 1e6, 1),
-                "d2h_mb_per_s": round(out["d2h_bytes_per_s"] / 1e6, 1),
-                "rtt_ms": round(rtt * 1e3, 2),
-                "probe_bytes": nbytes,
-            }
-        )
-        return out
-    except Exception as e:  # the probe must never sink the artifact
-        _emit_line(
-            {
-                "metric": "transfer_probe_link", "value": 0.0, "unit": "MB/s",
-                "error": (str(e).splitlines() or [repr(e)])[0][:200],
-            }
-        )
-        return None
-
-
 def main() -> None:
     from dsort_tpu.utils.backend import use_compile_cache
 
@@ -1022,79 +946,18 @@ def _main_body() -> None:
 
     _cli._bench_suite(argparse.Namespace(reps=reps, emit=_emit_line))
 
-    # Measure the host<->device link itself (VERDICT r5 weak #1 / next #4 —
-    # the productized scratch/probe_transfer.py): warm H2D/D2H bandwidth on
-    # a bulk buffer plus the small-transfer round-trip time.  The e2e
-    # phase-split rows below derive `expected_transfer_s` from these, so
-    # their host_fraction decomposes into link vs code from the artifact
-    # alone.
-    link = _probe_transfer(reps)
-
-    # Phase split of one end-to-end SPMD sort: 'partition' (host prep + H2D)
-    # and 'assemble' (D2H + host concat) are the host<->device transfers;
-    # 'spmd_sort' is the on-device program.
     from dsort_tpu.config import JobConfig
     from dsort_tpu.data.ingest import gen_uniform
     from dsort_tpu.parallel.mesh import local_device_mesh
     from dsort_tpu.parallel.sample_sort import SampleSort
-    from dsort_tpu.utils.metrics import Metrics
 
-    mesh = local_device_mesh()
-    ss = SampleSort(mesh, JobConfig(local_kernel=kernel))
-
-    def _phase_split(label: str, nkeys: int, seed: int) -> float:
-        u = gen_uniform(nkeys, seed=seed)
-        ss.sort(u)  # warm
-        m = Metrics()
-        t0 = time.perf_counter()
-        ss.sort(u, metrics=m)
-        total = time.perf_counter() - t0
-        host_s = m.phase_s.get("partition", 0.0) + m.phase_s.get("assemble", 0.0)
-        host_fraction = round(host_s / total, 3)
-        extra = {}
-        if link is not None:
-            # The data plane moves the keys down once (partition) and up
-            # once (assemble), with ~3 dispatch round-trips (input put,
-            # execute+scalar fetch, range fetches).  Subtracting the link's
-            # expected share from the measured host time attributes the
-            # host_fraction: `_link` is what the measured bandwidth/RTT
-            # predicts, `_code` is what the host phases cost beyond it.
-            expected = (
-                u.nbytes / link["h2d_bytes_per_s"]
-                + u.nbytes / link["d2h_bytes_per_s"]
-                + 3 * link["rtt_s"]
-            )
-            extra = {
-                "expected_transfer_s": round(expected, 4),
-                "host_fraction_link": round(min(expected, host_s) / total, 3),
-                "host_fraction_code": round(
-                    max(host_s - expected, 0.0) / total, 3
-                ),
-            }
-        _emit(
-            label, nkeys / total, "keys/sec",
-            phases_seconds={
-                k: round(v, 4) for k, v in sorted(m.phase_s.items())
-            },
-            # partition+assemble share of wall time; the *_link/*_code
-            # split above attributes it to the link or the host code.
-            host_fraction=host_fraction,
-            **extra,
-        )
-        return total
-
-    t_1m = _phase_split("spmd_sort_1M_end_to_end_phase_split", 1 << 20, 9)
-    # At-scale e2e: the data plane's host phases must not grow faster
-    # than the device phase (VERDICT r4 next #1 'holds at scale').
-    _phase_split("spmd_sort_2p26_end_to_end_phase_split", 1 << 26, 10)
+    ss = SampleSort(local_device_mesh(), JobConfig(local_kernel=kernel))
 
     # Device-resident e2e + on-device validation (VERDICT r5 next #5): the
     # path a real pipeline stage uses — the sorted array STAYS sharded on
     # the mesh (`keep_on_device` -> DeviceSortResult), and `dsort validate`
     # semantics (order + FNV multiset checksum) run as jitted shard_map
-    # reductions with only scalars crossing to the host.  The phase-split
-    # rows above measure the relay; this row is the sort.  Same 1M data as
-    # the 1M phase split, so `speedup_vs_relay_e2e` is like-for-like.
+    # reductions with only scalars crossing to the host.
     try:
         from dsort_tpu.models.validate import _multiset
 
@@ -1116,12 +979,9 @@ def _main_body() -> None:
             and rep_v.records == len(u1m)
             and rep_v.checksum == _multiset(u1m, len(u1m), u1m.dtype.itemsize)
         )
-        extra = {}
-        if t_1m > 0:
-            extra["speedup_vs_relay_e2e"] = round(t_1m / dt, 1)
         _emit(
             f"sort_e2e_device_resident_1M_{chip}",
-            (1 << 20) / dt, "keys/sec", validated_ok=ok, **extra,
+            (1 << 20) / dt, "keys/sec", validated_ok=ok,
         )
         # The on-device validate cost as its own metric: what `dsort
         # validate` semantics cost when nothing relays to the host.

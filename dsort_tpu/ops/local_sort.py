@@ -46,9 +46,13 @@ def sort_keys(keys: jax.Array) -> jax.Array:
 
 
 def _apply_perm(payload: jax.Array, perm: jax.Array, axis: int) -> jax.Array:
-    """Apply a per-slice sort permutation to a payload with trailing dims."""
-    idx = perm.reshape(perm.shape + (1,) * (payload.ndim - perm.ndim))
-    return jnp.take_along_axis(payload, idx, axis=axis)
+    """Apply a per-slice sort permutation to a payload with trailing dims.
+
+    Runs under the named scope ``payload_gather``, so the row gather is
+    found by name in a device trace."""
+    with jax.named_scope("payload_gather"):
+        idx = perm.reshape(perm.shape + (1,) * (payload.ndim - perm.ndim))
+        return jnp.take_along_axis(payload, idx, axis=axis)
 
 
 def sort_kv(

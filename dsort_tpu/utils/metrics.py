@@ -96,7 +96,9 @@ class Metrics:
             return self._job_ord
 
     def total_s(self) -> float:
-        return sum(self.phase_s.values())
+        """Seconds of the top-level phases; a ``<phase>.<step>`` key is a
+        part of its phase and is not counted twice."""
+        return sum(v for k, v in self.phase_s.items() if "." not in k)
 
     def keys_per_sec(self, n_keys: int) -> float:
         t = self.total_s()
@@ -120,16 +122,27 @@ class PhaseTimer:
     (`utils.tracing.annotate`), so when a profile capture is active
     (``dsort run --profile-dir`` / `tracing.profile_trace`) the host-side
     phases line up against device ops in the TensorBoard/Perfetto timeline.
+
+    A dotted name ``<phase>.<step>`` (or `step` inside an open phase) times
+    one step of a phase: its seconds land under that key and its annotation
+    is ``dsort:<phase>.<step>``, but it journals no ``phase_start`` /
+    ``phase_end``, so every event-fed consumer (the journal, ``dsort
+    report``, the ``/metrics`` phase series, health) sees only the phases.
+    A step entered several times in one phase (once per shard) sums.
     """
 
     def __init__(self, metrics: Metrics):
         self.metrics = metrics
+        self._open: list[str] = []  # the top-level phases entered, innermost last
 
     @contextlib.contextmanager
     def phase(self, name: str):
         from dsort_tpu.utils.tracing import annotate
 
-        self.metrics.event("phase_start", phase=name)
+        top = "." not in name
+        if top:
+            self.metrics.event("phase_start", phase=name)
+            self._open.append(name)
         t0 = time.perf_counter()
         try:
             with annotate(f"dsort:{name}"):
@@ -137,4 +150,12 @@ class PhaseTimer:
         finally:
             dt = time.perf_counter() - t0
             self.metrics.add(name, dt)
-            self.metrics.event("phase_end", phase=name, seconds=round(dt, 6))
+            if top:
+                self._open.pop()
+                self.metrics.event("phase_end", phase=name, seconds=round(dt, 6))
+
+    def step(self, step: str):
+        """Time ``step`` of the innermost open phase as ``<phase>.<step>``."""
+        if not self._open:
+            raise RuntimeError(f"step {step!r} outside any phase")
+        return self.phase(f"{self._open[-1]}.{step}")
