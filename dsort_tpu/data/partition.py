@@ -6,6 +6,12 @@ and aborts above 4,096 ints per chunk (``server.c:185-216``).
 `equal_partition` keeps exactly those remainder semantics, uncapped;
 `pad_to_shards` produces the static-shape ``(W, cap)`` layout + counts that the
 SPMD phases require.
+
+When every shard is exactly ``cap`` long (``len(data) == W * cap``) and the
+input is C-contiguous, that layout already is the input: the pad functions
+return a read-only ``reshape`` view of it, with no pad written and no byte
+copied.  Any other input (a short shard, an explicit larger ``cap``, a
+strided array) is copied into a fresh buffer with sentinel or zero pads.
 """
 
 from __future__ import annotations
@@ -33,6 +39,32 @@ def partition(data: np.ndarray, num_workers: int) -> list[np.ndarray]:
     return out
 
 
+def _shard_cap(sizes: list[int], multiple: int, cap: int | None) -> int:
+    """The layout's row length: the largest shard rounded up to ``multiple``,
+    or an explicit ``cap`` checked to hold the largest shard."""
+    if cap is None:
+        return -(-max(sizes + [1]) // multiple) * multiple
+    if cap < max(sizes + [0]):
+        raise ValueError(f"cap {cap} < largest shard {max(sizes)}")
+    return cap
+
+
+def _exact_view(data: np.ndarray, rows: int, cap: int) -> np.ndarray | None:
+    """``data`` as a read-only ``(rows, cap, ...)`` view when that is the
+    padded layout byte for byte: every shard full and the array C-contiguous.
+    ``None`` when the layout needs a copy.  Read-only, so a caller that
+    writes into its layout fails instead of changing the caller's input."""
+    if (
+        not isinstance(data, np.ndarray)
+        or len(data) != rows * cap
+        or not data.flags.c_contiguous
+    ):
+        return None
+    out = data.reshape((rows, cap) + data.shape[1:])
+    out.flags.writeable = False
+    return out
+
+
 def pad_to_shards(
     data: np.ndarray, num_workers: int, multiple: int = 8, cap: int | None = None
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -42,13 +74,15 @@ def pad_to_shards(
     alignment); pads hold the dtype sentinel.  This is the static-shape
     successor of the reference's malloc'd variable chunks (``server.c:206-216``).
     An explicit ``cap`` overrides the computed one — multi-host drivers must
-    agree on one global cap even when hosts hold unequal data amounts.
+    agree on one global cap even when hosts hold unequal data amounts.  When
+    no pad is needed the layout is a read-only view of ``data`` (module doc).
     """
     sizes = equal_partition(len(data), num_workers)
-    if cap is None:
-        cap = -(-max(sizes + [1]) // multiple) * multiple
-    elif cap < max(sizes + [0]):
-        raise ValueError(f"cap {cap} < largest shard {max(sizes)}")
+    cap = _shard_cap(sizes, multiple, cap)
+    counts = np.asarray(sizes, dtype=np.int32)
+    view = _exact_view(data, num_workers, cap)
+    if view is not None:
+        return view, counts
     # np.empty + per-row TAIL fill, not np.full: only the pad gaps are
     # written twice, so the host cost is one pass over the data plus the
     # (usually tiny) padding — not two passes (VERDICT r4 next #1).
@@ -59,7 +93,7 @@ def pad_to_shards(
         out[i, :s] = data[off : off + s]
         out[i, s:] = sent
         off += s
-    return out, np.asarray(sizes, dtype=np.int32)
+    return out, counts
 
 
 def pad_to_layout(
@@ -69,11 +103,17 @@ def pad_to_layout(
 
     Companion channels (e.g. a secondary sort key) reuse the sizes/cap a prior
     `pad_to_shards`/`pad_kv_to_shards` call computed, instead of re-partitioning.
-    Pads hold ``fill``.
+    Pads hold ``fill``; when every count is ``cap`` the layout is a read-only
+    view of ``data`` (module doc).
     """
+    counts = np.asarray(counts)
+    if (counts == cap).all():
+        view = _exact_view(data, len(counts), cap)
+        if view is not None:
+            return view
     out = np.full((len(counts), cap) + data.shape[1:], fill, dtype=data.dtype)
     off = 0
-    for i, s in enumerate(np.asarray(counts)):
+    for i, s in enumerate(counts):
         out[i, :s] = data[off : off + s]
         off += s
     return out
@@ -89,13 +129,17 @@ def pad_kv_to_shards(
     """Key+payload variant of `pad_to_shards`; payload pads are zeros.
 
     Like `pad_to_shards`, an explicit ``cap`` lets multi-host drivers agree
-    on one global layout across hosts with unequal record counts.
+    on one global layout across hosts with unequal record counts.  When no
+    pad is needed and both arrays are C-contiguous, both are read-only views
+    of the input (module doc).
     """
     sizes = equal_partition(len(keys), num_workers)
-    if cap is None:
-        cap = -(-max(sizes + [1]) // multiple) * multiple
-    elif cap < max(sizes + [0]):
-        raise ValueError(f"cap {cap} < largest shard {max(sizes)}")
+    cap = _shard_cap(sizes, multiple, cap)
+    counts = np.asarray(sizes, dtype=np.int32)
+    view_k = _exact_view(keys, num_workers, cap)
+    view_v = _exact_view(payload, num_workers, cap)
+    if view_k is not None and view_v is not None:
+        return view_k, view_v, counts
     out_k = np.full((num_workers, cap), sentinel_for(keys.dtype), dtype=keys.dtype)
     out_v = np.zeros((num_workers, cap) + payload.shape[1:], dtype=payload.dtype)
     off = 0
@@ -103,4 +147,4 @@ def pad_kv_to_shards(
         out_k[i, :s] = keys[off : off + s]
         out_v[i, :s] = payload[off : off + s]
         off += s
-    return out_k, out_v, np.asarray(sizes, dtype=np.int32)
+    return out_k, out_v, counts

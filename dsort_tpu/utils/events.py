@@ -227,6 +227,8 @@ COUNTERS: dict[str, str] = {
     "multihost_resort_keys": "keys re-sorted by the multi-host resume path",
     "batch_jobs_restored": "batched jobs served from checkpoint",
     "padded_elems": "elements allocated in batched padding layouts",
+    "partition_views": "dispatches whose host shard layout was a view of "
+                       "the input (every shard full), not a padded copy",
     "fused_small_jobs": "jobs served by the fused single-program path",
     "fused_fallbacks": "fused-path failures retried on the SPMD scheduler",
     "runs_resumed": "external-sort runs restored from a previous run",

@@ -139,6 +139,52 @@ def test_a_capacity_retry_adds_its_upload_to_the_same_steps():
     _children_sum_to_parents(m.phase_s)
 
 
+def _inputs(kind, n):
+    x = _keys(n, seed=n)
+    if kind == "retry":
+        return (np.full(n, 7, np.int32),)
+    if kind != "kv_secondary":
+        return (x,)
+    payload = np.broadcast_to(x.astype(np.uint8)[:, None], (n, 12)).copy()
+    return (x // 4).astype(np.uint64), payload, (x % 4).astype(np.int32)
+
+
+@pytest.mark.parametrize("kind,n,exact", [
+    (kind, n, exact)
+    for kind in ("sort", "resident", "kv_secondary")
+    for n, exact in ((20_000, True), (20_004, False))
+] + [("retry", 40_000, True)])
+def test_full_shards_upload_a_view_of_the_input(kind, n, exact):
+    """4 shards of 5000 (8 of 5000 for the retry) are the layout as they
+    stand; 4 of 5001 pad to 5008 in a copy."""
+    from dsort_tpu.config import JobConfig
+
+    inp = _inputs(kind, n)
+    before = [a.copy() for a in inp]
+    m = Metrics()
+    if kind == "retry":
+        ss = SampleSort(local_device_mesh(8), JobConfig(capacity_factor=1.0))
+    else:
+        ss = SampleSort(local_device_mesh(4))
+    if kind == "kv_secondary":
+        keys, payload, sec = inp
+        ok, ov = ss.sort_kv(keys, payload, metrics=m, secondary=sec)
+        order = np.lexsort((sec, keys))
+        np.testing.assert_array_equal(ok, keys[order])
+        np.testing.assert_array_equal(ov, payload[order])
+    else:
+        (x,) = inp
+        out = ss.sort(x, metrics=m, keep_on_device=kind == "resident")
+        out = out.to_host() if kind == "resident" else out
+        np.testing.assert_array_equal(out, np.sort(x))
+    for a, b in zip(inp, before):
+        np.testing.assert_array_equal(a, b)
+    assert m.counters["partition_views"] == int(exact)
+    assert "partition.pad" in m.phase_s
+    if kind == "retry":
+        assert m.counters["capacity_retries"] >= 1
+
+
 def test_journal_phase_series_and_total_of_a_sort_are_the_phases():
     from dsort_tpu.obs.telemetry import Telemetry
 
