@@ -1618,6 +1618,8 @@ class SampleSort:
         slot_bytes = keys.dtype.itemsize + int(
             np.prod(sv.shape[2:], dtype=np.int64)
         ) * sv.dtype.itemsize
+        if secondary is not None:  # the secondary plane crosses the wire too
+            slot_bytes += ss.dtype.itemsize
         if exch in ("ring", "fused"):
             out_k, out_v, c = self._dispatch_kv_ring(
                 xs, vs, cj, n_local, tuple(sv.shape[2:]), slot_bytes,
