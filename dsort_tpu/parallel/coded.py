@@ -336,10 +336,9 @@ def snapshot_kv_state(
     check_ring_overflow(ov)
     c = np.asarray(c).reshape(-1)
     mh = np.asarray(mh).reshape(p, -1)
-    mv = np.asarray(mv)
-    mv = mv.reshape((p, mv.shape[0] // p) + mv.shape[1:])
     rv = np.asarray(rv)
     rv = rv.reshape((p, r1) + rv.shape[1:])
+    mv = np.asarray(mv).reshape((p, -1) + rv.shape[3:])  # rows of a flat payload
     return CodedExchangeState(
         num_workers=p,
         redundancy=int(redundancy),
@@ -373,10 +372,9 @@ def snapshot_parity_kv_state(
     check_ring_overflow(ov)
     c = np.asarray(c).reshape(-1)
     mh = np.asarray(mh).reshape(p, -1)
-    mv = np.asarray(mv)
-    mv = mv.reshape((p, mv.shape[0] // p) + mv.shape[1:])
     sv = np.asarray(sv)
     sv = sv.reshape((p, sv.shape[0] // p) + sv.shape[1:])
+    mv = np.asarray(mv).reshape((p, -1) + sv.shape[2:])  # rows of a flat payload
     pk = np.asarray(pk)
     pv = np.asarray(pv)
     return CodedExchangeState(

@@ -442,21 +442,47 @@ def _device_program(timer, uploaded=()):
             yield
 
 
-def _land_shards(timer, arrays, c, n: int, p: int) -> list[np.ndarray]:
+def _flat_rows(fn, at: int):
+    """``fn`` with its output ``at``, a payload of rows, flattened to 1-D.
+
+    XLA gives a 2-D ``u8[rows, 92]`` output the column-major layout
+    ``{0,1}`` on the TPU, so its host copy arrives with strides
+    ``(1, rows)`` and lands through a transposing copy.  A 1-D array has one
+    layout on every backend and no lane padding: its host copy is the rows
+    in order, which `_land_shards` reshapes back (``row_shapes``) as a view.
+    The wrapper keeps ``fn``'s name, which names the jitted program.
+    """
+
+    def flat(*args):
+        outs = fn(*args)
+        return (*outs[:at], outs[at].reshape(-1), *outs[at + 1:])
+
+    flat.__name__ = flat.__qualname__ = getattr(fn, "func", fn).__name__
+    return flat
+
+
+def _land_shards(
+    timer, arrays, c, n: int, p: int, row_shapes=None
+) -> list[np.ndarray]:
     """Copy the first ``c[i]`` rows of shard ``i`` of each of ``arrays``
     into one output buffer per array, in shard order.
 
     An array is a 1-axis-sharded device array or, after a straggler serve,
-    a host list of per-shard ranges.  Runs inside the ``assemble`` phase:
-    the waits for each shard's host copy are ``assemble.d2h`` (the copies
-    start together, `_shard_rows`), the copies into the buffers
-    ``assemble.land``, each summed over the shards.
+    a host list of per-shard ranges.  ``row_shapes[j]`` is the row shape of
+    a flat ``arrays[j]`` (`_flat_rows`); each shard's host copy is reshaped
+    to rows without a copy.  Runs inside the ``assemble`` phase: the waits
+    for each shard's host copy are ``assemble.d2h`` (the copies start
+    together, `_shard_rows`), the copies into the buffers ``assemble.land``,
+    each summed over the shards.  A host copy that is not C-contiguous
+    lands through a strided copy and bumps ``land_strided_copies``.
     """
+    row_shapes = row_shapes or [()] * len(arrays)
     with timer.step("d2h"):
         rows = [a.__getitem__ if isinstance(a, list) else _shard_rows(a, p)
                 for a in arrays]
     outs = [np.empty(n, dtype=a[0].dtype if a else np.int32) if isinstance(a, list)
-            else np.empty((n,) + a.shape[1:], dtype=a.dtype) for a in arrays]
+            else np.empty((n,) + a.shape[1:] + rs, dtype=a.dtype)
+            for a, rs in zip(arrays, row_shapes)]
     off = 0
     for i in range(p):
         ci = int(c[i])
@@ -464,6 +490,9 @@ def _land_shards(timer, arrays, c, n: int, p: int) -> list[np.ndarray]:
             got = [row(i) for row in rows]
         with timer.step("land"):
             for out, g in zip(outs, got):
+                g = g.reshape((-1,) + out.shape[1:])
+                if not g.flags.c_contiguous:
+                    timer.metrics.bump("land_strided_copies")
                 out[off : off + ci] = g[:ci]
         off += ci
     if off != n:  # a short concat was detectable; a torn buffer is not
@@ -556,17 +585,17 @@ class SampleSort:
             in_specs = (P(self.axis), P(self.axis))
             out_specs = (P(self.axis),) * 4
         elif secondary:
-            fn = functools.partial(
+            fn = _flat_rows(functools.partial(
                 _sample_sort_kv2_shard, merge_kernel=self.job.merge_kernel,
                 kernel=self.job.local_kernel, **kwargs
-            )
+            ), 2)
             in_specs = (P(self.axis),) * 4
             out_specs = (P(self.axis),) * 6
         else:
-            fn = functools.partial(
+            fn = _flat_rows(functools.partial(
                 _sample_sort_kv_shard, merge_kernel=self.job.merge_kernel,
                 kernel=self.job.local_kernel, **kwargs
-            )
+            ), 1)
             in_specs = (P(self.axis), P(self.axis), P(self.axis))
             out_specs = (P(self.axis),) * 5
         # Donate the keys buffer on the keys-only path: the merged output
@@ -683,7 +712,7 @@ class SampleSort:
             in_specs = (P(self.axis), P(self.axis), P())
             out_specs = (P(self.axis),) * 3
         else:
-            fn = functools.partial(_ring_exchange_kv_shard, **kwargs)
+            fn = _flat_rows(functools.partial(_ring_exchange_kv_shard, **kwargs), 1)
             in_specs = (P(self.axis), P(self.axis), P(self.axis), P())
             out_specs = (P(self.axis),) * 4
         # Same donation policy as `_build`: the sorted keys buffer is dead
@@ -758,6 +787,8 @@ class SampleSort:
             in_specs = (P(self.axis), P(self.axis), P(self.axis), P())
             n_out = 9 if parity else 7
         fn = functools.partial(shard_fn, **kwargs)
+        if kv_trailing is not None:
+            fn = _flat_rows(fn, 1)
         tag = ("spmd_parity" if parity else "spmd_coded") + (
             "" if kv_trailing is None else "_kv"
         )
@@ -805,7 +836,7 @@ class SampleSort:
             in_specs = (P(self.axis), P(self.axis), P(), P())
             out_specs = (P(self.axis),) * 3
         else:
-            fn = functools.partial(fused_ring_exchange_kv_shard, **kwargs)
+            fn = _flat_rows(functools.partial(fused_ring_exchange_kv_shard, **kwargs), 1)
             in_specs = (P(self.axis), P(self.axis), P(self.axis), P(), P())
             out_specs = (P(self.axis),) * 4
         # Donation policy matches `_build_ring`: no retry exists past the
@@ -1655,7 +1686,9 @@ class SampleSort:
             else:
                 raise RuntimeError("sample sort bucket overflow after max retries")
         with timer.phase("assemble"):
-            keys_out, vals_out = _land_shards(timer, [out_k, out_v], c, len(keys), p)
+            keys_out, vals_out = _land_shards(
+                timer, [out_k, out_v], c, len(keys), p, [(), tuple(sv.shape[2:])]
+            )
         return keys_out, vals_out
 
 

@@ -229,6 +229,8 @@ COUNTERS: dict[str, str] = {
     "padded_elems": "elements allocated in batched padding layouts",
     "partition_views": "dispatches whose host shard layout was a view of "
                        "the input (every shard full), not a padded copy",
+    "land_strided_copies": "shard copies landed from a host buffer that was "
+                           "not C-contiguous (a strided, transposing copy)",
     "fused_small_jobs": "jobs served by the fused single-program path",
     "fused_fallbacks": "fused-path failures retried on the SPMD scheduler",
     "runs_resumed": "external-sort runs restored from a previous run",

@@ -602,3 +602,100 @@ def test_kv_merge_block_pairs_path(monkeypatch):
         got_set = {bytes(r) for r in got}
         want_set = {bytes(r) for r in want}
         assert got_set == want_set and len(got) == len(want)
+
+
+KV_LANDING = [(sec, exch) for sec in (True, False) for exch in ("alltoall", "ring")]
+
+
+@pytest.mark.parametrize("secondary,exchange", KV_LANDING,
+                         ids=[f"{'sec' if s else 'nosec'}-{e}" for s, e in KV_LANDING])
+def test_sample_sort_kv_payload_lands_row_major(mesh8, secondary, exchange):
+    # Eight devices: the payload comes back as one C-contiguous buffer,
+    # landed with no strided copy, records exactly the input's.
+    from dsort_tpu.data.ingest import terasort_secondary
+
+    keys, payload = gen_terasort(10_000, seed=31)
+    m = Metrics()
+    sk, sv = SampleSort(mesh8, JobConfig(key_dtype=np.uint64)).sort_kv(
+        keys, payload, metrics=m, exchange=exchange,
+        secondary=terasort_secondary(payload) if secondary else None,
+    )
+    assert sv.shape == payload.shape and sv.flags.c_contiguous
+    assert m.counters.get("land_strided_copies", 0) == 0
+    np.testing.assert_array_equal(sk, np.sort(keys))
+    assert sorted(zip(sk.tolist(), map(bytes, sv))) == sorted(
+        zip(keys.tolist(), map(bytes, payload))
+    )
+
+
+def _shard_spec(mesh, shape, dtype):
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, PartitionSpec("w")))
+
+
+def test_land_shards_counts_strided_copies(mesh8):
+    # A column-major payload (the layout XLA gives a 2-D u8 output on the
+    # TPU) still lands row for row, and each shard's copy is counted.
+    import jax
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from dsort_tpu.parallel.sample_sort import _land_shards
+    from dsort_tpu.utils.metrics import PhaseTimer
+
+    p, rows = 8, 64
+    host = np.random.default_rng(5).integers(0, 256, (p * rows, 12), dtype=np.uint8)
+    col_major = Format(Layout(major_to_minor=(1, 0)), NamedSharding(mesh8, PartitionSpec("w")))
+    arr = jax.jit(lambda x: x + 0, out_shardings=col_major)(host)
+    assert not np.asarray(arr.addressable_shards[0].data).flags.c_contiguous
+    c = np.full(p, rows - 3)
+    m = Metrics()
+    timer = PhaseTimer(m)
+    with timer.phase("assemble"):
+        (out,) = _land_shards(timer, [arr], c, int(c.sum()), p)
+    np.testing.assert_array_equal(
+        out, host.reshape(p, rows, 12)[:, : rows - 3].reshape(-1, 12))
+    assert m.counters["land_strided_copies"] == p
+    # The same rows flattened on the device land with plain copies.
+    flat = jax.jit(lambda x: x.reshape(-1))(jax.device_put(
+        host, NamedSharding(mesh8, PartitionSpec("w"))))
+    m2 = Metrics()
+    timer = PhaseTimer(m2)
+    with timer.phase("assemble"):
+        (out2,) = _land_shards(timer, [flat], c, int(c.sum()), p, [(12,)])
+    np.testing.assert_array_equal(out2, out)
+    assert m2.counters.get("land_strided_copies", 0) == 0
+
+
+@pytest.mark.parametrize("kind", ["spmd_kv", "spmd_kv2", "spmd_ring_kv"])
+def test_kv_programs_return_the_payload_flat(mesh8, kind):
+    # The payload leaves every record program as a 1-D array (rows x 92
+    # bytes): one layout on every backend, so its host copy is row-major.
+    p, n_local, width = 8, 256, 92
+    ss = SampleSort(mesh8, JobConfig(key_dtype=np.uint64))
+    keys = _shard_spec(mesh8, (p * n_local,), np.uint64)
+    payload = _shard_spec(mesh8, (p * n_local, width), np.uint8)
+    counts = _shard_spec(mesh8, (p,), np.int32)
+    if kind == "spmd_ring_kv":
+        import jax
+
+        fn = ss._build_ring(n_local, (64,) * p, (width,))
+        args = [keys, payload, counts, jax.ShapeDtypeStruct((p - 1,), np.uint64)]
+        at = 1
+    elif kind == "spmd_kv2":
+        fn = ss._build(n_local, 64, (width,), True)
+        args = [keys, _shard_spec(mesh8, (p * n_local,), np.uint16), payload, counts]
+        at = 2
+    else:
+        fn = ss._build(n_local, 64, (width,), False)
+        args = [keys, payload, counts]
+        at = 1
+    lowered = fn._fn.lower(*args)
+    out = lowered.out_info[at]
+    assert out.ndim == 1 and out.dtype == np.uint8 and out.shape[0] % (p * width) == 0
+    # The program keeps its shard body's name, which the device trace shows.
+    name = "_ring_exchange_kv_shard" if kind == "spmd_ring_kv" else (
+        "_sample_sort_kv2_shard" if kind == "spmd_kv2" else "_sample_sort_kv_shard")
+    assert f"@jit_{name}" in lowered.as_text()

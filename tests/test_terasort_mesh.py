@@ -67,9 +67,10 @@ def _reference(prefix, payload):
     return prefix[order], payload[order]
 
 
-def _in_byte_order_within_ties(prefix, payload):
-    """The output with each run of equal 10-byte keys put in byte order."""
-    tail = payload[:, 0].astype(np.uint32) << 8 | payload[:, 1]
+def _in_byte_order_within_ties(prefix, payload, key_tail=True):
+    """The output with each run of equal 10-byte keys (equal prefixes where
+    ``key_tail`` is false) put in byte order."""
+    tail = payload[:, 0].astype(np.uint32) << 8 | payload[:, 1] if key_tail else prefix
     cols = [payload[:, j] for j in range(payload.shape[1] - 1, -1, -1)]
     run = np.r_[0, np.cumsum((prefix[1:] != prefix[:-1]) | (tail[1:] != tail[:-1]))]
     order = np.lexsort(cols + [run])
@@ -124,3 +125,38 @@ def test_terasort_records_across_four_devices(mesh4, case):
     caps = [cap_pair_policy(n_local, JobConfig().capacity_factor, P)] + tap.caps
     assert m.counters["exchange_bytes_on_wire"] == sum(
         alltoall_wire_bytes(c, SLOT_BYTES, P) for c in caps)
+
+
+#: (devices, secondary, exchange, case) of the landing cases: a ring asked
+#: for on one device runs the all-to-all, and a secondary with the ring
+#: runs the all-to-all too, as `dsort terasort` would.
+LANDING = [(p, sec, exch, case) for p in (1, P) for sec in (True, False)
+           for exch in ("alltoall", "ring")
+           for case in ("uniform-ragged", "prefix-collisions")]
+
+
+@pytest.mark.parametrize(
+    "p,secondary,exchange,case", LANDING,
+    ids=[f"{p}dev-{'sec' if s else 'nosec'}-{e}-{c}" for p, s, e, c in LANDING])
+def test_terasort_payload_lands_row_major(devices, p, secondary, exchange, case):
+    """The payload leaves the device in row order: the output is one
+    C-contiguous ``(n, 92)`` buffer landed by plain copies, and it is the
+    reference's records."""
+    from dsort_tpu.parallel.mesh import local_device_mesh
+
+    make, n = CASES[case]
+    prefix, payload = make(np.random.default_rng(p * 7919 + n), n)
+    m = Metrics()
+
+    out_p, out_v = SampleSort(local_device_mesh(p)).sort_kv(
+        prefix, payload, metrics=m, exchange=exchange,
+        secondary=terasort_secondary(payload) if secondary else None)
+
+    assert out_v.shape == (n, 92) and out_v.flags.c_contiguous
+    assert m.counters.get("land_strided_copies", 0) == 0
+    ref_p, ref_v = _reference(prefix, payload)
+    np.testing.assert_array_equal(out_p, ref_p)
+    if secondary:
+        np.testing.assert_array_equal(out_v[:, :2], ref_v[:, :2])
+    _, out_v = _in_byte_order_within_ties(out_p, out_v, key_tail=secondary)
+    np.testing.assert_array_equal(out_v, ref_v)
