@@ -14,75 +14,8 @@ baseline:  ## record current findings as tolerated (ship this file EMPTY)
 test:  ## tier-1 suite (excludes slow/sanitizer tests)
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow'
 
-bench-smoke:  ## device-resident sort + on-device validate on the 8-device cpu mesh
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --device-resident --n 200000 --reps 2 \
-	--journal /tmp/dsort_bench_smoke.jsonl
-
-bench-exchange-smoke:  ## three-way alltoall/ring/fused exchange A/B (uniform + zipf + kv) on the 8-device cpu mesh
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --exchange-ab --n 200000 --reps 2 \
-	--journal /tmp/dsort_bench_exchange_smoke.jsonl
-
-# The fused-ring smoke is the same one-copy A/B harness — the fused arm
-# rides --exchange-ab so the three schedules always measure the same data.
-bench-fused-smoke: bench-exchange-smoke  ## fused Pallas ring kernel A/B smoke (alias of bench-exchange-smoke)
-
-fused-smoke: bench-fused-smoke  ## alias: ISSUE 11 CI name for the fused-ring smoke
-
-serve-smoke:  ## mixed small/large two-tenant workload through the real serving queue (8-device cpu mesh)
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --serve-mixed --n 400000 --reps 1 \
-	--journal /tmp/dsort_serve_smoke.jsonl
-
-fleet-smoke:  ## federated serving: 2 local agents behind a fleet controller, locality/random/health routing A/B + telemetry-overhead baseline (8-device cpu mesh)
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --fleet-mixed --n 20000 --reps 1 \
-	--journal /tmp/dsort_fleet_smoke.jsonl
-
 spec-smoke:  ## explicit-state model check of the fleet protocol (bounded, backend-free, seconds)
 	$(PY) -m dsort_tpu.cli spec check --max-states 12000
-
-profile-smoke:  ## introspection-plane cost proof: ring sort with vs without journal+ledger+memwatch (8-device cpu mesh)
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --analyze-smoke --n 1048576 --reps 2 \
-	--journal /tmp/dsort_profile_smoke.jsonl
-
-external-smoke:  ## out-of-core wave pipeline: 8x-over-budget sort, overlap A/B + mid-wave fault drill (8-device cpu mesh)
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --external-wave --n 262144 --reps 1 \
-	--journal /tmp/dsort_external_smoke.jsonl
-
-coded-smoke:  ## coded-redundancy failure A/B: redundancy=1 vs 2, healthy vs one injected loss, bit-identical gate (8-device cpu mesh)
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --coded-ab --n 131072 --reps 1 \
-	--journal /tmp/dsort_coded_smoke.jsonl
-
-coded-v2-smoke:  ## coded v2 acceptance A/B: parity-vs-replicate wire premium, per-mode loss drills, straggler p99 race (8-device cpu mesh)
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --coded-v2-ab --n 131072 --reps 1 \
-	--journal /tmp/dsort_coded_v2_smoke.jsonl
-
-autotune-smoke:  ## closed-loop planner A/B: hand-set alltoall/ring vs planner-chosen exchange, bit-identical + correct-pick gate (8-device cpu mesh)
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --autotune-ab --n 131072 --reps 1 \
-	--journal /tmp/dsort_autotune_smoke.jsonl
-
-hier-smoke:  ## two-level pod exchange A/B: flat ring vs hier at simulated HxD topologies + device/host-loss drills, bit-identical + DCN-reduction gate (8-device cpu mesh)
-	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-	$(PY) -m dsort_tpu.cli bench --hier-ab --n 131072 --reps 1 \
-	--journal /tmp/dsort_hier_smoke.jsonl
-
-# Regression diff over versioned bench artifacts (tolerance ladder:
-# ok >= 0.95 > noise >= 0.80 > regression >= 0.50 > severe); exits 1 on
-# severe (STRICT=1: also on regression).  Backend-free.
-OLD ?= BENCH_r06.jsonl
-NEW ?= BENCH_r07.jsonl
-bench-compare:  ## diff two bench artifacts: make bench-compare OLD=a NEW=b [STRICT=1]
-	$(PY) bench.py --compare $(OLD) $(NEW) $(if $(STRICT),--strict,)
-
-bench-history:  ## the whole in-tree BENCH_r*.jsonl perf trajectory as one metric x PR table
-	$(PY) bench.py --history
 
 native:  ## build libdsort_native.so
 	$(MAKE) -C $(NATIVE)
@@ -101,4 +34,4 @@ ubsan:  ## build + run the native selftest under UBSanitizer
 
 sanitize: tsan asan ubsan  ## all three sanitizer selftest runs
 
-.PHONY: lint lint-sarif baseline test bench-smoke bench-exchange-smoke bench-fused-smoke fused-smoke serve-smoke fleet-smoke spec-smoke profile-smoke external-smoke coded-smoke coded-v2-smoke autotune-smoke hier-smoke bench-compare bench-history native tsan asan ubsan sanitize
+.PHONY: lint lint-sarif baseline test spec-smoke native tsan asan ubsan sanitize

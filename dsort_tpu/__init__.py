@@ -30,7 +30,7 @@ Package layout:
   runtime/    native C++ runtime (k-way merge, worker table, TCP coordinator)
   utils/      structured logging, metrics, profiling hooks
   checkpoint  sorted-shard persistence for partial recovery
-  cli         dsort run/serve/bench/gen/coordinator/worker
+  cli         dsort run/serve/fleet/gen/terasort/external/validate/...
 """
 
 __version__ = "0.1.0"

@@ -4,14 +4,13 @@ The reference's entire user surface is a stdin REPL on the master ("type a
 filename, get output.txt", ``server.c:160-167``) plus conf-file argv
 (``server.c:100-103``).  The CLI keeps that workflow (`dsort serve` is the
 REPL; conf files in the reference's own format are accepted) and adds the
-one-shot, benchmark, data-generation, cluster, and worker entry points a real
-tool needs.
+one-shot, data-generation, cluster, and worker entry points a real tool
+needs.
 
   dsort run INPUT [-o OUT]      one sort job (file -> file)
   dsort run --device-resident   same, sorted array stays on the mesh and
                                 validates on device (no relay)
   dsort serve                   REPL: filenames on stdin until 'exit'
-  dsort bench                   throughput benchmark, one JSON line
   dsort gen N -o FILE           synthetic inputs (uniform / zipf)
   dsort coordinator             native TCP coordinator for a worker cluster
   dsort worker                  worker shim joining a coordinator
@@ -870,1765 +869,6 @@ def cmd_fleet(args) -> int:
             signal.signal(signal.SIGTERM, old_term)
         if server is not None:
             server.close()
-
-
-_REF_KEYS_PER_SEC = 16_384 / 0.374  # BASELINE.md measured reference throughput
-
-
-def _bench_suite(args) -> int:
-    """The BASELINE config ladder, one JSON line per config.
-
-    1. the reference's own workload (its 16,384-key maximum, ``server.c:13``)
-    2. 1M uniform int32, SPMD sample sort over the local mesh
-    3. 1M uniform int64 (needs x64; cli.main enabled it)
-    4. TeraSort records (full 10-byte key + 90 B payload), kv shuffle
-    5. 1M Zipf-skewed keys WITH one injected worker failure
-    """
-    import jax
-
-    from dsort_tpu.config import JobConfig
-    from dsort_tpu.data.ingest import gen_terasort, gen_uniform, gen_zipf, terasort_secondary
-    from dsort_tpu.parallel.mesh import local_device_mesh
-    from dsort_tpu.parallel.sample_sort import SampleSort
-    from dsort_tpu.scheduler import FaultInjector, SpmdScheduler
-
-    mesh = local_device_mesh()
-    reps = args.reps
-    if reps < 1:  # bench.py calls _bench_suite directly, not via cmd_bench
-        raise SystemExit("--reps must be >= 1")
-    # bench.py passes its recording emitter so the ladder lines join the
-    # artifact's final summary line; standalone `dsort bench` just prints.
-    emit = getattr(args, "emit", None) or (
-        lambda line: print(json.dumps(line), flush=True)
-    )
-
-    def timed(label, n, unit, fn, **extra):
-        fn()  # warm/compile
-        times = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            fn()
-            times.append(time.perf_counter() - t0)
-        # min, not median: dispatch jitter is one-sided additive noise (same
-        # doctrine as bench.py's chain timing), and the ladder's end-to-end
-        # numbers were swinging ~3x between runs on the median.
-        dt = float(min(times))
-        line = {
-            "metric": label,
-            "value": round(n / dt, 1),
-            "unit": unit,
-            # host->host timing of the public API: includes device dispatch
-            # and the host<->device transfers, which dominate the small
-            # configs.
-            "includes_host_roundtrip": True,
-        }
-        if unit == "keys/sec":
-            # rec/sec vs the reference's keys/sec is not apples-to-apples;
-            # only same-unit configs get a vs_baseline ratio (ADVICE r1).
-            line["vs_baseline"] = round(n / dt / _REF_KEYS_PER_SEC, 2)
-        line.update(extra)
-        emit(line)
-
-    ss32 = SampleSort(mesh)
-    ref = gen_uniform(16_384, seed=0)
-    # Config 1 routes exactly as `dsort run` would (the CLI's small-job
-    # auto-route, VERDICT r2 item 3): ONE fused device program — the whole
-    # reference job (server.c:160-268) in ~2 host round trips.
-    from dsort_tpu.models.pipelines import fused_sort_small
-
-    # Floor decomposition for the one head-to-head row the reference
-    # defines (VERDICT r5 next #8): `device_ms` is the pure executable cost
-    # (slope over k back-to-back runs on device-resident input — queued
-    # executions amortize dispatch, one fetch at the end), and
-    # `fixed_overhead_ms_per_dispatch` is the e2e single-job wall minus
-    # that — the host round-trip + dispatch floor the headline ratio is
-    # actually bound by.  Attributable from the artifact alone.  The e2e
-    # reps measured here ARE the config1 line (emitted inline in timed()'s
-    # shape) — re-running them through timed() would double config1's wall
-    # cost for the same min.
-    c1_label = "config1_reference_workload_16384_int32"
-    try:
-        from dsort_tpu.models.pipelines import _fused_small_fn
-
-        import jax as _jax
-
-        n1 = len(ref)
-        f1 = _fused_small_fn(n1, str(ref.dtype), "auto")  # n1 is 2^14: no pad
-        # DEVICE-resident input: a host buffer would re-pay H2D on every
-        # chained call and inflate device_ms with transfer cost.
-        buf1 = _jax.device_put(np.ascontiguousarray(ref))
-        np.asarray(f1(buf1, np.int32(n1))[-1:])  # warm/compile
-
-        def _dev_total(k: int) -> float:
-            times = []
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                for _ in range(k):
-                    y = f1(buf1, np.int32(n1))
-                np.asarray(y[-1:])
-                times.append(time.perf_counter() - t0)
-            return float(min(times))
-
-        device_s = max((_dev_total(10) - _dev_total(2)) / 8, 0.0)
-        fused_sort_small(ref)  # warm the host-path wrapper
-        e2e_times = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            fused_sort_small(ref)
-            e2e_times.append(time.perf_counter() - t0)
-        e2e_s = float(min(e2e_times))  # min: one-sided dispatch jitter
-        emit({
-            "metric": c1_label,
-            "value": round(n1 / e2e_s, 1),
-            "unit": "keys/sec",
-            "includes_host_roundtrip": True,
-            "vs_baseline": round(n1 / e2e_s / _REF_KEYS_PER_SEC, 2),
-            "mode": "fused_local",
-            "device_ms": round(device_s * 1e3, 3),
-            "fixed_overhead_ms_per_dispatch": round(
-                max(e2e_s - device_s, 0.0) * 1e3, 2
-            ),
-        })
-    except Exception as e:  # decomposition must never sink the ladder
-        log.warning("config1 floor decomposition failed: %s", e)
-        timed(c1_label, len(ref), "keys/sec",
-              lambda: fused_sort_small(ref), mode="fused_local")
-    u32 = gen_uniform(1 << 20, seed=1)
-    timed("config2_uniform_1M_int32_spmd", len(u32), "keys/sec",
-          lambda: ss32.sort(u32))
-    u64 = gen_uniform(1 << 20, dtype=np.int64, seed=2)
-    ss64 = SampleSort(mesh, JobConfig(key_dtype=np.int64))
-    timed("config3_uniform_1M_int64_spmd", len(u64), "keys/sec",
-          lambda: ss64.sort(u64))
-    tk, tv = gen_terasort(1 << 16, seed=3)
-    tsec = terasort_secondary(tv)
-    sst = SampleSort(mesh, JobConfig(key_dtype=np.uint64, payload_bytes=tv.shape[1]))
-    timed("config4_terasort_65536_records_kv", len(tk), "rec/sec",
-          lambda: sst.sort_kv(tk, tv, secondary=tsec))
-    z = gen_zipf(1 << 20, a=1.3, seed=4)
-    if len(jax.devices()) >= 4:
-        # One scheduler reused across reps (its per-device-set SampleSort
-        # cache keeps the SPMD programs compiled); the injector re-arms each
-        # call so EVERY rep really recovers from a failure — verified below.
-        inj = FaultInjector()
-        sched = SpmdScheduler(job=JobConfig(settle_delay_s=0.01), injector=inj)
-
-        def faulted():
-            inj.fail_once(2, "spmd")
-            m = Metrics()
-            sched.sort(z, metrics=m)
-            if not m.counters.get("mesh_reforms"):
-                raise RuntimeError("config5: injected failure did not fire")
-
-        timed("config5_zipf_1M_with_injected_failure", len(z), "keys/sec",
-              faulted)
-    else:
-        # Injection needs a mesh to lose a device from; on a single-device
-        # host the 'with failure' label would be a lie — measure and say so.
-        log.warning("config5: <4 devices, failure injection inactive")
-        ss5 = SampleSort(mesh)
-        timed("config5_zipf_1M_no_failure_single_device", len(z), "keys/sec",
-              lambda: ss5.sort(z))
-    return 0
-
-
-def _bench_device_resident(args, cfg: SortConfig) -> int:
-    """`dsort bench --device-resident`: the no-relay e2e + validate lines.
-
-    Times (a) device-resident sort — handle creation is already
-    synchronized by the retry-scalar fetch, so the wall time is honest e2e
-    with NO key ever crossing the relay — and (b) the on-device validation
-    pass, each as its own JSON line (min over reps; one-sided jitter
-    doctrine).  This is also the `make bench-smoke` target, tier-1-gated in
-    `tests/test_device_resident.py`.
-    """
-    from dsort_tpu.data.ingest import gen_uniform
-    from dsort_tpu.models.validate import _multiset
-
-    dtype = np.dtype(cfg.job.key_dtype)
-    data = gen_uniform(args.n, dtype=dtype, seed=0)
-    sched = _make_device_scheduler(cfg)
-    journal = _open_journal(args)
-    handle = sched.sort(data, keep_on_device=True)  # warm sort program
-    handle.validate_on_device()                     # warm validator
-    sort_times, val_times = [], []
-    rep = None
-    try:
-        for _ in range(args.reps):
-            metrics = Metrics(journal=journal)
-            t0 = time.perf_counter()
-            handle = sched.sort(data, metrics=metrics, keep_on_device=True)
-            sort_times.append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            rep = handle.validate_on_device()
-            val_times.append(time.perf_counter() - t0)
-    finally:
-        _write_journal(journal, args)
-    in_sum = _multiset(data, len(data), dtype.itemsize)
-    ok = bool(rep.sorted_ok and rep.records == len(data)
-              and rep.checksum == in_sum)
-    dt, dtv = float(min(sort_times)), float(min(val_times))
-    for line in (
-        {
-            "metric": f"sort_e2e_device_resident_{dtype}_{args.n}_keys",
-            "value": round(args.n / dt, 1),
-            "unit": "keys/sec",
-            "vs_baseline": round(args.n / dt / _REF_KEYS_PER_SEC, 2),
-        },
-        {
-            "metric": f"device_validate_{dtype}_{args.n}_keys",
-            "value": round(args.n / dtv, 1),
-            "unit": "keys/sec",
-            "validated_ok": ok,
-        },
-    ):
-        print(json.dumps(line), flush=True)
-    return 0 if ok else 1
-
-
-def _bench_exchange_ab(args, cfg: SortConfig) -> int:
-    """`dsort bench --exchange-ab`: the three-way exchange A/B on the local
-    mesh — lax all_to_all vs lax ring vs the FUSED Pallas ring kernel.
-
-    The `make bench-exchange-smoke` / `make bench-fused-smoke` targets
-    (tier-1-gated like bench-smoke), and THE exchange harness — bench.py's
-    cpu-mesh ladder shells out to this command so the A/B contract lives in
-    one place: for a uniform int32, a zipf-skewed int64, and a TeraSort kv
-    workload, sorts the same data through every schedule, asserts the
-    outputs bit-identical, and emits per workload (a) the unchanged
-    ring-vs-alltoall row with both throughputs and the measured per-sort
-    ``bytes_on_wire`` of each schedule (the counter charges every attempt —
-    an overflowed padded dispatch pays for its failed shipment too), and
-    (b) a ``exchange_fused_vs_ring_*`` row whose structural axis is
-    ``dispatches_per_exchange``: the lax ring issues P-1 ppermute
-    collectives per exchange, the fused kernel exactly ONE launch
-    (`ops.ring_kernel`).  On the CPU mesh the fused end-to-end figure is a
-    dispatch-overhead comparison only — the comm/compute overlap the kernel
-    exists for needs real ICI.
-    """
-    import jax
-
-    from dsort_tpu.config import JobConfig
-    from dsort_tpu.data.ingest import gen_terasort, gen_uniform, gen_zipf
-    from dsort_tpu.parallel.exchange import dispatches_per_exchange
-    from dsort_tpu.parallel.mesh import local_device_mesh
-    from dsort_tpu.parallel.sample_sort import SampleSort
-
-    mesh = local_device_mesh(cfg.mesh.num_workers)
-    # Guard on the mesh ACTUALLY used (a NUM_WORKERS=1 config on an
-    # 8-device host would otherwise silently benchmark alltoall against
-    # itself — resolve_exchange forces ring back to alltoall at P=1).
-    if mesh.shape["w"] < 2:
-        raise SystemExit(
-            "--exchange-ab needs a multi-worker mesh (the ring and the "
-            "all_to_all are the same program on one worker); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8 without "
-            "NUM_WORKERS=1"
-        )
-    journal = _open_journal(args)
-    tk, tv = gen_terasort(1 << 16, seed=3)
-    cases = [
-        (
-            f"uniform_int32_{args.n}",
-            gen_uniform(args.n, seed=0),
-            None,
-            JobConfig(local_kernel=cfg.job.local_kernel),
-        ),
-        (
-            f"zipf_int64_{args.n}",
-            gen_zipf(args.n, a=1.3, seed=4),
-            None,
-            JobConfig(key_dtype=np.int64, local_kernel=cfg.job.local_kernel),
-        ),
-        (
-            "kv_65536_records",
-            tk,
-            tv,
-            JobConfig(
-                key_dtype=np.uint64, payload_bytes=tv.shape[1],
-                local_kernel=cfg.job.local_kernel,
-            ),
-        ),
-    ]
-    ok_all = True
-    try:
-        for label, keys, payload, job in cases:
-            ss = SampleSort(mesh, job)
-
-            def run(exch, m=None):
-                if payload is None:
-                    return ss.sort(keys, metrics=m, exchange=exch)
-                return ss.sort_kv(keys, payload, metrics=m, exchange=exch)
-
-            def canonical(out):
-                # Keys-only: the sorted array compares directly.  kv: keys
-                # must be bit-identical AND the records the same multiset —
-                # payload order among EQUAL keys is unspecified on both
-                # schedules (unstable local sorts), so compare records in a
-                # canonical (key, payload-bytes) order; this is what
-                # catches a ring payload-permutation bug that ships sorted
-                # keys over scrambled values.
-                if payload is None:
-                    return out
-                k, v = out
-                order = np.lexsort(
-                    tuple(v[:, i] for i in range(v.shape[1])) + (k,)
-                )
-                return k, k[order].tobytes() + v[order].tobytes()
-
-            results, stats = {}, {}
-            for exch in ("alltoall", "ring", "fused"):
-                run(exch)  # warm/compile
-                times = []
-                m = Metrics(journal=journal)
-                for _ in range(args.reps):
-                    t0 = time.perf_counter()
-                    out = run(exch, m)
-                    times.append(time.perf_counter() - t0)
-                results[exch] = canonical(out)
-                # Counters accumulated over the reps: report EVERYTHING
-                # per-sort (each rep restarts from the policy capacity, so
-                # retries divide evenly like the bytes).
-                stats[exch] = {
-                    "dt": float(min(times)),  # one-sided jitter doctrine
-                    "bytes": m.counters.get("exchange_bytes_on_wire", 0)
-                    // args.reps,
-                    "retries": m.counters.get("capacity_retries", 0)
-                    // args.reps,
-                    "saved": m.counters.get("exchange_bytes_saved", 0)
-                    // args.reps,
-                    "launches": m.counters.get("fused_exchange_launches", 0)
-                    // args.reps,
-                }
-
-            def same(a, b):
-                if payload is None:
-                    return bool(np.array_equal(results[a], results[b]))
-                return bool(
-                    np.array_equal(results[a][0], results[b][0])
-                ) and results[a][1] == results[b][1]
-
-            identical = same("alltoall", "ring")
-            fused_identical = same("ring", "fused")
-            ok_all = ok_all and identical and fused_identical
-            n = len(keys)
-            p = mesh.shape["w"]
-            unit = "keys/sec" if payload is None else "rec/sec"
-            print(json.dumps({
-                "metric": f"exchange_ring_vs_alltoall_{label}",
-                "value": round(n / stats["ring"]["dt"], 1),
-                "unit": unit,
-                "alltoall_keys_per_sec": round(
-                    n / stats["alltoall"]["dt"], 1
-                ),
-                "speedup_vs_alltoall": round(
-                    stats["alltoall"]["dt"] / stats["ring"]["dt"], 2
-                ),
-                "bytes_on_wire": stats["ring"]["bytes"],
-                "bytes_on_wire_alltoall": stats["alltoall"]["bytes"],
-                "bytes_saved": stats["ring"]["saved"],
-                "capacity_retries_alltoall": stats["alltoall"]["retries"],
-                "capacity_retries_ring": stats["ring"]["retries"],
-                "bit_identical": identical,
-            }), flush=True)
-            print(json.dumps({
-                "metric": f"exchange_fused_vs_ring_{label}",
-                "value": round(n / stats["fused"]["dt"], 1),
-                "unit": unit,
-                "ring_keys_per_sec": round(n / stats["ring"]["dt"], 1),
-                "speedup_vs_ring": round(
-                    stats["ring"]["dt"] / stats["fused"]["dt"], 2
-                ),
-                "dispatches_per_exchange": dispatches_per_exchange(
-                    "fused", p
-                ),
-                "dispatches_per_exchange_ring": dispatches_per_exchange(
-                    "ring", p
-                ),
-                "fused_launches_per_sort": stats["fused"]["launches"],
-                "bytes_on_wire": stats["fused"]["bytes"],
-                "capacity_retries": stats["fused"]["retries"],
-                "bit_identical": fused_identical,
-            }), flush=True)
-    finally:
-        _write_journal(journal, args)
-    return 0 if ok_all else 1
-
-
-def _bench_coded_ab(args, cfg: SortConfig) -> int:
-    """`dsort bench --coded-ab`: the coded-redundancy failure A/B.
-
-    The `make coded-smoke` target (tier-1-gated) and THE acceptance
-    harness for the coded plane (ARCHITECTURE §14): the SAME zipf workload
-    through `SpmdScheduler` four ways — redundancy=1 vs 2, healthy vs one
-    injected mid-ring device loss.  The uncoded faulted arm recovers by
-    today's re-form-and-re-run (the measured ~2.4x hit of
-    ``config5_zipf_1M_injected_failure``); the coded faulted arm recovers
-    by a LOCAL merge of replica slots — counter-asserted: exactly one
-    ``coded_recoveries`` per faulted sort, zero re-dispatch.  Every arm's
-    output must be bit-identical to ``np.sort``; the rows report
-    ``throughput_under_failure_ratio`` (coded faulted vs uncoded healthy)
-    next to the re-run baseline's ratio and the healthy-path replica
-    overhead (``replica_overhead_frac`` — the availability premium: ~r x
-    exchange wire bytes).  Healthy arms warm once and report min-of-reps;
-    each FAULTED rep runs on a FRESH scheduler (healthy warm pass off the
-    clock) so the timed run pays its true recovery cost — for the re-run
-    arm that is the re-dispatch PLUS the re-formed mesh's recompile
-    (exactly what ``config5_zipf_1M_injected_failure`` measured as the
-    2.4x hit, and exactly what the coded arm structurally avoids).
-    """
-    import jax
-
-    from dsort_tpu.config import JobConfig
-    from dsort_tpu.data.ingest import gen_zipf
-    from dsort_tpu.scheduler import FaultInjector, SpmdScheduler
-
-    devices = jax.devices()
-    if len(devices) < 2:
-        raise SystemExit(
-            "--coded-ab needs a multi-device mesh (there is no replica "
-            "holder on one device); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8"
-        )
-    # The injected victim must exist on THIS mesh, whatever its size —
-    # device 3 on the canonical 8-device mesh, the last device otherwise
-    # (its r=2 replica holder is device 0, wrapping the ring).
-    victim = min(3, len(devices) - 1)
-    journal = _open_journal(args)
-    data = gen_zipf(args.n, a=1.3, seed=5)
-    expect = np.sort(data)
-    n = len(data)
-
-    def make_sched(red: int):
-        inj = FaultInjector()
-        return inj, SpmdScheduler(
-            devices=devices,
-            job=JobConfig(
-                settle_delay_s=0.01, exchange="ring", redundancy=red,
-                key_dtype=np.int64, local_kernel=cfg.job.local_kernel,
-            ),
-            injector=inj,
-        )
-
-    def run_arm(red: int, fault: bool):
-        times = []
-        m = Metrics(journal=journal)
-        out = None
-        if not fault:
-            _, sched = make_sched(red)
-            sched.sort(data)  # warm the healthy P-device programs
-            for _ in range(args.reps):
-                t0 = time.perf_counter()
-                out = sched.sort(data, metrics=m)
-                times.append(time.perf_counter() - t0)
-            return float(min(times)), m, out
-        for _ in range(args.reps):
-            # A fresh scheduler per faulted rep: the timed sort pays its
-            # TRUE recovery cost — the re-run arm's re-dispatch includes
-            # the re-formed mesh's recompile (the config5 semantics); the
-            # coded arm never re-dispatches, so it pays only the replica
-            # fetch + local merge.
-            inj, sched = make_sched(red)
-            sched.sort(data)  # healthy warm pass, off the clock
-            inj.fail_once(victim, "ring")
-            t0 = time.perf_counter()
-            out = sched.sort(data, metrics=m)
-            times.append(time.perf_counter() - t0)
-        return float(min(times)), m, out
-
-    try:
-        arms = {}
-        ok_all = True
-        for red, fault in ((1, False), (2, False), (1, True), (2, True)):
-            dt, m, out = run_arm(red, fault)
-            identical = bool(np.array_equal(out, expect))
-            ok_all = ok_all and identical
-            arms[(red, fault)] = {
-                "dt": dt,
-                "identical": identical,
-                "coded_recoveries": m.counters.get("coded_recoveries", 0)
-                // args.reps,
-                "recovered_keys": m.counters.get("coded_recovered_keys", 0)
-                // args.reps,
-                "replica_bytes": m.counters.get("coded_replica_bytes", 0)
-                // args.reps,
-                "mesh_reforms": m.counters.get("mesh_reforms", 0)
-                // args.reps,
-            }
-        h1, h2 = arms[(1, False)], arms[(2, False)]
-        f1, f2 = arms[(1, True)], arms[(2, True)]
-        # Contract: the coded faulted arm recovers locally (one coded
-        # recovery per sort, zero re-sorted keys) — not just fast.
-        ok_all = ok_all and f2["coded_recoveries"] == 1
-        print(json.dumps({
-            "metric": f"coded_redundancy_healthy_zipf_{args.n}",
-            "value": round(n / h2["dt"], 1),
-            "unit": "keys/sec",
-            "baseline_keys_per_sec": round(n / h1["dt"], 1),
-            "replica_overhead_frac": round(
-                max(h2["dt"] - h1["dt"], 0.0) / h1["dt"], 4
-            ),
-            "redundancy": 2,
-            "coded_replica_bytes": h2["replica_bytes"],
-            "bit_identical": h1["identical"] and h2["identical"],
-        }), flush=True)
-        print(json.dumps({
-            "metric": f"coded_redundancy_failure_zipf_{args.n}",
-            "value": round(n / f2["dt"], 1),
-            "unit": "keys/sec",
-            "baseline_keys_per_sec": round(n / h1["dt"], 1),
-            "rerun_keys_per_sec": round(n / f1["dt"], 1),
-            "throughput_under_failure_ratio": round(h1["dt"] / f2["dt"], 3),
-            "rerun_failure_ratio": round(h1["dt"] / f1["dt"], 3),
-            "redundancy": 2,
-            "coded_recoveries": f2["coded_recoveries"],
-            "recovered_keys": f2["recovered_keys"],
-            "mesh_reforms": f2["mesh_reforms"],
-            "includes_reform_and_recompile": True,
-            "bit_identical": all(a["identical"] for a in arms.values()),
-        }), flush=True)
-    finally:
-        _write_journal(journal, args)
-    return 0 if ok_all else 1
-
-
-def _bench_coded_v2_ab(args, cfg: SortConfig) -> int:
-    """`dsort bench --coded-v2-ab`: the coded-exchange v2 acceptance A/B.
-
-    The `make coded-v2-smoke` target (tier-1-gated) and THE acceptance
-    harness for the v2 parity plane + straggler serving (ARCHITECTURE
-    §18): the §14 zipf workload through `SpmdScheduler` at r=2,
-    replicate vs parity — equal single-loss survivability — plus the
-    injected-straggler drill.  Three rows, all gated (ok -> exit 0):
-
-    - ``coded_v2_parity_premium``: healthy-path wire premium.  Parity
-      must ship < 0.75x replicate's measured ``coded_replica_bytes`` on
-      the same plan (one XOR slot vs a full replica per range).
-    - ``coded_v2_parity_failure``: one injected mid-ring loss per mode.
-      BOTH modes must recover locally — exactly one coded recovery per
-      faulted sort, zero re-sorted keys (the parity arm SOLVES the dead
-      range from its XOR slot; faulted reps run on a fresh scheduler
-      with the healthy warm pass off the clock, the §14 semantics).
-    - ``coded_v2_straggler``: `FaultInjector.slow` names a live-but-slow
-      owner; the coded plane races owner fetch vs reconstruction and
-      the p99 sort completion with serving ON must beat the
-      wait-on-owner baseline — measured from the SAME reps as the
-      losing owner leg's own completion time (`join_stragglers` drain),
-      which pays the injected delay the serve dodged.  Exactly one
-      ``coded_straggler_serves`` per rep, no failure, no mesh re-form.
-
-    Every arm's output must be bit-identical to ``np.sort``.
-    """
-    import math
-
-    import jax
-
-    from dsort_tpu.config import JobConfig
-    from dsort_tpu.data.ingest import gen_zipf
-    from dsort_tpu.scheduler import FaultInjector, SpmdScheduler
-
-    devices = jax.devices()
-    if len(devices) < 2:
-        raise SystemExit(
-            "--coded-v2-ab needs a multi-device mesh (there is no parity "
-            "holder on one device); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8"
-        )
-    victim = min(3, len(devices) - 1)  # replica/parity holder wraps the ring
-    slow_s = 0.5  # the injected straggler's extra owner-fetch latency
-    journal = _open_journal(args)
-    data = gen_zipf(args.n, a=1.3, seed=5)
-    expect = np.sort(data)
-    n = len(data)
-
-    def make_sched(red: int, mode: str):
-        inj = FaultInjector()
-        return inj, SpmdScheduler(
-            devices=devices,
-            job=JobConfig(
-                settle_delay_s=0.01, exchange="ring", redundancy=red,
-                redundancy_mode=mode, key_dtype=np.int64,
-                local_kernel=cfg.job.local_kernel,
-            ),
-            injector=inj,
-        )
-
-    def drain(sched) -> None:
-        for ss in sched._sorters.values():
-            ss.join_stragglers()
-
-    def p99(times: list) -> float:
-        ts = sorted(times)
-        return float(ts[max(0, math.ceil(0.99 * len(ts)) - 1)])
-
-    def run_arm(red: int, mode: str, shape: str):
-        """shape: 'healthy' | 'loss' | 'slow'.  Returns (times, owner
-        times, per-arm metrics, last output): faulted/slow reps each run
-        on a FRESH scheduler with a healthy warm pass off the clock, so
-        the timed sort pays its true recovery/serve cost."""
-        times, owner_times = [], []
-        m = Metrics(journal=journal)
-        out = None
-        if shape == "healthy":
-            _, sched = make_sched(red, mode)
-            sched.sort(data)  # warm the healthy P-device programs
-            for _ in range(args.reps):
-                t0 = time.perf_counter()
-                out = sched.sort(data, metrics=m)
-                times.append(time.perf_counter() - t0)
-            return times, owner_times, m, out
-        for _ in range(args.reps):
-            inj, sched = make_sched(red, mode)
-            sched.sort(data)  # healthy warm pass, off the clock
-            if shape == "loss":
-                inj.fail_once(victim, "ring")
-            else:
-                inj.slow(victim, slow_s)
-            t0 = time.perf_counter()
-            out = sched.sort(data, metrics=m)
-            times.append(time.perf_counter() - t0)
-            # The losing owner leg is still sleeping out the injected
-            # delay; its completion time IS the wait-on-owner baseline
-            # for this rep (what the sort would have cost without the
-            # race).  Drain it before the next rep so claims stay 1/rep.
-            drain(sched)
-            owner_times.append(time.perf_counter() - t0)
-        return times, owner_times, m, out
-
-    try:
-        arms = {}
-        ok_all = True
-        for red, mode, shape in (
-            (1, "replicate", "healthy"),
-            (2, "replicate", "healthy"),
-            (2, "parity", "healthy"),
-            (2, "replicate", "loss"),
-            (2, "parity", "loss"),
-            (2, "parity", "slow"),
-        ):
-            times, owner_times, m, out = run_arm(red, mode, shape)
-            identical = bool(np.array_equal(out, expect))
-            ok_all = ok_all and identical
-            arms[(red, mode, shape)] = {
-                "dt": float(min(times)),
-                "p99": p99(times),
-                "p99_owner": p99(owner_times) if owner_times else 0.0,
-                "identical": identical,
-                "coded_recoveries": m.counters.get("coded_recoveries", 0)
-                // args.reps,
-                "recovered_keys": m.counters.get("coded_recovered_keys", 0)
-                // args.reps,
-                "replica_bytes": m.counters.get("coded_replica_bytes", 0)
-                // args.reps,
-                "straggler_serves": m.counters.get(
-                    "coded_straggler_serves", 0
-                ) // args.reps,
-                "resort_keys": m.counters.get("shuffle_resort_keys", 0),
-                "mesh_reforms": m.counters.get("mesh_reforms", 0)
-                // args.reps,
-            }
-        h0 = arms[(1, "replicate", "healthy")]
-        hr, hp = arms[(2, "replicate", "healthy")], arms[(2, "parity", "healthy")]
-        fr, fp = arms[(2, "replicate", "loss")], arms[(2, "parity", "loss")]
-        sl = arms[(2, "parity", "slow")]
-        premium = hp["replica_bytes"] / max(hr["replica_bytes"], 1)
-        # Gate 1: parity's availability premium undercuts replication.
-        ok_all = ok_all and hp["replica_bytes"] > 0 and premium < 0.75
-        # Gate 2: both modes recover the injected loss LOCALLY.
-        for f in (fr, fp):
-            ok_all = (
-                ok_all and f["coded_recoveries"] == 1
-                and f["resort_keys"] == 0
-            )
-        # Gate 3: serving beats waiting on the slow owner, exactly once,
-        # with no failure machinery involved.
-        ok_all = (
-            ok_all and sl["straggler_serves"] == 1
-            and sl["p99"] < sl["p99_owner"]
-            and sl["mesh_reforms"] == 0
-        )
-        print(json.dumps({
-            "metric": f"coded_v2_parity_premium_zipf_{args.n}",
-            "value": round(n / hp["dt"], 1),
-            "unit": "keys/sec",
-            "baseline_keys_per_sec": round(n / h0["dt"], 1),
-            "replicate_keys_per_sec": round(n / hr["dt"], 1),
-            "replica_overhead_frac": round(
-                max(hp["dt"] - h0["dt"], 0.0) / h0["dt"], 4
-            ),
-            "redundancy": 2,
-            "redundancy_mode": "parity",
-            "coded_replica_bytes": hp["replica_bytes"],
-            "replicate_replica_bytes": hr["replica_bytes"],
-            "premium_ratio": round(premium, 4),
-            "bit_identical": hp["identical"] and hr["identical"],
-        }), flush=True)
-        print(json.dumps({
-            "metric": f"coded_v2_parity_failure_zipf_{args.n}",
-            "value": round(n / fp["dt"], 1),
-            "unit": "keys/sec",
-            "baseline_keys_per_sec": round(n / h0["dt"], 1),
-            "replicate_keys_per_sec": round(n / fr["dt"], 1),
-            "throughput_under_failure_ratio": round(h0["dt"] / fp["dt"], 3),
-            "redundancy": 2,
-            "redundancy_mode": "parity",
-            "coded_recoveries": fp["coded_recoveries"],
-            "recovered_keys": fp["recovered_keys"],
-            "mesh_reforms": fp["mesh_reforms"],
-            "includes_reform_and_recompile": True,
-            "bit_identical": fp["identical"] and fr["identical"],
-        }), flush=True)
-        print(json.dumps({
-            "metric": f"coded_v2_straggler_zipf_{args.n}",
-            "value": round(n / sl["p99"], 1),
-            "unit": "keys/sec",
-            "baseline_keys_per_sec": round(n / h0["dt"], 1),
-            "p99_serve_s": round(sl["p99"], 4),
-            "p99_owner_s": round(sl["p99_owner"], 4),
-            "speedup_vs_wait": round(sl["p99_owner"] / sl["p99"], 2),
-            "slow_s": slow_s,
-            "redundancy": 2,
-            "redundancy_mode": "parity",
-            "straggler_serves": sl["straggler_serves"],
-            "mesh_reforms": sl["mesh_reforms"],
-            "bit_identical": sl["identical"],
-        }), flush=True)
-    finally:
-        _write_journal(journal, args)
-    return 0 if ok_all else 1
-
-
-def _bench_hier_ab(args, cfg: SortConfig) -> int:
-    """`dsort bench --hier-ab`: the pod-scale two-level exchange A/B.
-
-    The `make hier-smoke` target (tier-1-gated) and THE acceptance harness
-    for the hierarchical exchange plane (ARCHITECTURE §17): one zipf
-    workload sorted flat-ring and two-level at every simulated ``H x D``
-    topology the local mesh divides into, then the fault drills.  Gates
-    (ok -> exit 0):
-
-    - every arm's output bit-identical to ``np.sort`` (the schedule may
-      only change HOW keys move, never WHAT comes back);
-    - at every topology the journaled ``dcn_bytes_on_wire`` is LESS than
-      what the flat ring would have pushed across the same host boundary
-      for the same measured histogram (``ring_dcn_bytes``; the
-      ``dcn_bytes_saved`` counter is exactly the difference) — the
-      tentpole claim, measured, not asserted;
-    - the DEVICE-loss drill re-forms within the host: losing devices of
-      one host mid-exchange keeps the ``H``-host grouping (journaled
-      ``hier_reform`` with ``hosts_before == hosts_after``) and returns
-      bit-identical output;
-    - the HOST-loss drill re-plans: losing ALL of one host's devices
-      mid-phase-two re-forms the survivors under the largest divisor the
-      mesh still supports (``hier_reform`` with ``hosts_after <
-      hosts_before``) and returns bit-identical output.
-
-    One JSON row per topology (throughputs + the DCN/intra wire split)
-    plus one row per drill.
-    """
-    from dsort_tpu.config import JobConfig
-    from dsort_tpu.data.ingest import gen_zipf
-    from dsort_tpu.parallel.mesh import local_device_mesh
-    from dsort_tpu.parallel.sample_sort import SampleSort
-    from dsort_tpu.scheduler import FaultInjector, SpmdScheduler
-    from dsort_tpu.utils.events import EventLog
-
-    mesh = local_device_mesh(cfg.mesh.num_workers)
-    p = int(mesh.shape["w"])
-    if p < 4:
-        raise SystemExit(
-            "--hier-ab needs >= 4 devices (two simulated hosts of two "
-            "devices is the smallest two-level topology); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8"
-        )
-    journal = _open_journal(args) or EventLog()
-    data = gen_zipf(args.n, a=1.3, seed=6)
-    expect = np.sort(data)
-    n = len(data)
-    # Every >=2-host grouping with >=2 devices per host the mesh divides
-    # into — on the canonical 8-device mesh: 2x4 and 4x2.
-    topologies = [h for h in (2, 4, 8) if h < p and p % h == 0 and p // h >= 2]
-    job_kw = dict(key_dtype=np.int64, local_kernel=cfg.job.local_kernel)
-    ok_all = True
-    try:
-        ss_ring = SampleSort(mesh, JobConfig(exchange="ring", **job_kw))
-        ss_ring.sort(data)  # warm/compile
-        times = []
-        for _ in range(args.reps):
-            t0 = time.perf_counter()
-            ring_out = ss_ring.sort(data)
-            times.append(time.perf_counter() - t0)
-        ring_dt = float(min(times))
-        for hosts in topologies:
-            ss = SampleSort(
-                mesh, JobConfig(exchange="hier", hier_hosts=hosts, **job_kw)
-            )
-            ss.sort(data)  # warm/compile
-            m = Metrics(journal=journal)
-            times = []
-            for _ in range(args.reps):
-                t0 = time.perf_counter()
-                out = ss.sort(data, metrics=m)
-                times.append(time.perf_counter() - t0)
-            dt = float(min(times))
-            identical = bool(np.array_equal(out, expect)) and bool(
-                np.array_equal(ring_out, expect)
-            )
-            dcn = m.counters.get("dcn_bytes_on_wire", 0) // args.reps
-            intra = m.counters.get("intra_host_bytes_on_wire", 0) // args.reps
-            saved = m.counters.get("dcn_bytes_saved", 0) // args.reps
-            ring_dcn = dcn + saved  # the flat baseline, same histogram
-            reduced = saved > 0
-            ok = identical and reduced
-            ok_all = ok_all and ok
-            print(json.dumps({
-                "metric": f"hier_exchange_zipf_{args.n}_h{hosts}",
-                "value": round(n / dt, 1),
-                "unit": "keys/sec",
-                "hosts": hosts,
-                "dev_per_host": p // hosts,
-                "ring_keys_per_sec": round(n / ring_dt, 1),
-                "dcn_bytes": int(dcn),
-                "ring_dcn_bytes": int(ring_dcn),
-                "dcn_reduction_frac": round(saved / ring_dcn, 4)
-                if ring_dcn else 0.0,
-                "intra_host_bytes": int(intra),
-                "hier_exchanges": m.counters.get("hier_exchanges", 0)
-                // args.reps,
-                "bit_identical": identical,
-            }), flush=True)
-
-        # The fault drills: the hook fires between the (H, H) plan and the
-        # exchange dispatch (the schedule is sized, the legs are "in
-        # flight"), so a tripped loss invalidates the planned exchange and
-        # the survivors re-plan — the two-level fault contract, measured.
-        def drill(hosts: int, victims: list[int]):
-            inj = FaultInjector()
-            sched = SpmdScheduler(
-                devices=list(mesh.devices.flat),
-                job=JobConfig(
-                    settle_delay_s=0.01, exchange="hier", hier_hosts=hosts,
-                    **job_kw,
-                ),
-                injector=inj,
-            )
-            sched.sort(data)  # healthy warm pass, off the clock
-            for w in victims:
-                inj.fail_once(w, "ring")
-            m = Metrics(journal=journal)
-            t0 = time.perf_counter()
-            out = sched.sort(data, metrics=m)
-            dt = time.perf_counter() - t0
-            reforms = [
-                e for e in journal.events()
-                if e.type == "hier_reform"
-            ][-1:]
-            rf = reforms[0].fields if reforms else {}
-            identical = bool(np.array_equal(out, expect))
-            return dt, m, rf, identical
-
-        # Device loss, H=2: losing devices of host 0 (never the whole
-        # host) re-forms WITHIN the host — the 2-host grouping survives.
-        # Victims are chosen so the survivor count still divides by 2 (the
-        # 1-D simulation has no fixed per-host slot map, so an odd
-        # survivor count would force a downgrade a real pod's re-formed
-        # host group would not).
-        dev_victims = [1] if (p - 1) % 2 == 0 else [1, 2]
-        dt_dev, m_dev, rf_dev, id_dev = drill(2, dev_victims)
-        ok_dev = (
-            id_dev and rf_dev.get("hosts_before") == 2
-            and rf_dev.get("hosts_after") == 2
-            and not rf_dev.get("downgraded")
-        )
-        ok_all = ok_all and ok_dev
-        print(json.dumps({
-            "metric": f"hier_device_loss_drill_zipf_{args.n}",
-            "value": round(n / dt_dev, 1),
-            "unit": "keys/sec",
-            "hosts_before": rf_dev.get("hosts_before"),
-            "hosts_after": rf_dev.get("hosts_after"),
-            "downgraded": rf_dev.get("downgraded"),
-            "survivors": rf_dev.get("survivors"),
-            "mesh_reforms": m_dev.counters.get("mesh_reforms", 0),
-            "bit_identical": id_dev,
-        }), flush=True)
-        # Host loss, H=4 (when the mesh supports it): ALL of host 1's
-        # devices die mid-phase-two; the survivors no longer divide by 4,
-        # so the re-plan lands on the largest divisor they do support.
-        if 4 in topologies:
-            dh = p // 4
-            host_victims = list(range(dh, 2 * dh))
-            dt_host, m_host, rf_host, id_host = drill(4, host_victims)
-            ok_host = (
-                id_host and rf_host.get("hosts_before") == 4
-                and 2 <= int(rf_host.get("hosts_after") or 0) < 4
-                and not rf_host.get("downgraded")
-            )
-            ok_all = ok_all and ok_host
-            print(json.dumps({
-                "metric": f"hier_host_loss_drill_zipf_{args.n}",
-                "value": round(n / dt_host, 1),
-                "unit": "keys/sec",
-                "hosts_before": rf_host.get("hosts_before"),
-                "hosts_after": rf_host.get("hosts_after"),
-                "downgraded": rf_host.get("downgraded"),
-                "survivors": rf_host.get("survivors"),
-                "mesh_reforms": m_host.counters.get("mesh_reforms", 0),
-                "bit_identical": id_host,
-            }), flush=True)
-    finally:
-        if getattr(args, "journal", None):
-            journal.flush_jsonl(args.journal)
-    return 0 if ok_all else 1
-
-
-def _bench_autotune_ab(args, cfg: SortConfig) -> int:
-    """`dsort bench --autotune-ab`: does the planner pay for itself?
-
-    The `make autotune-smoke` target (tier-1-gated) and THE acceptance
-    harness for the planner plane (ARCHITECTURE §15): a zipf-skewed int64
-    workload and a uniform int32 workload, each sorted three ways on the
-    local mesh — exchange hand-set to alltoall, hand-set to ring, and a
-    third arm with ``autotune=True`` and the exchange knob genuinely
-    unset, so the planner's measured skew probe picks the schedule per
-    dispatch and journals a ``plan_decision``.  Gates (ok -> exit 0):
-
-    - every arm's output bit-identical (the planner may only change HOW
-      keys move, never WHAT comes back);
-    - the planner picks ring on the zipf workload and alltoall on the
-      uniform one (the measured ``max_mean_ratio`` vs
-      ``SKEW_RING_THRESHOLD`` contract — the zipf head lands ~P x the
-      mean bucket, uniform sits at ~1.0);
-    - the autotune arm lands within 0.95x of the BEST hand-set arm
-      (probe overhead must not eat the win).  Below 1M keys the
-      throughput gate relaxes to the structural checks only, the same
-      doctrine as ``--analyze-smoke``: at smoke sizes the fixed per-sort
-      dispatch cost drowns the schedule delta and the probe share, so
-      tiny runs check the plane end-to-end, the 1M ladder row checks the
-      number.
-
-    One JSON row per workload with both hand-set throughputs, the
-    autotune throughput, the chosen schedule, and the journaled
-    plan_decision count.
-    """
-    import dataclasses
-
-    from dsort_tpu.config import JobConfig
-    from dsort_tpu.data.ingest import gen_uniform, gen_zipf
-    from dsort_tpu.parallel.mesh import local_device_mesh
-    from dsort_tpu.parallel.sample_sort import SampleSort
-    from dsort_tpu.utils.events import EventLog
-
-    mesh = local_device_mesh(cfg.mesh.num_workers)
-    if mesh.shape["w"] < 2:
-        raise SystemExit(
-            "--autotune-ab needs a multi-worker mesh (every exchange "
-            "schedule is the same program on one worker); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8 without "
-            "NUM_WORKERS=1"
-        )
-    journal = _open_journal(args) or EventLog()
-    cases = [
-        (
-            f"zipf_int64_{args.n}",
-            gen_zipf(args.n, a=1.3, seed=4),
-            JobConfig(key_dtype=np.int64, local_kernel=cfg.job.local_kernel),
-            # No TPU on the cpu mesh: the skewed pick is the lax ring
-            # (fused is the TPU-gated upgrade of the same measured plan).
-            "ring",
-        ),
-        (
-            f"uniform_int32_{args.n}",
-            gen_uniform(args.n, seed=0),
-            JobConfig(local_kernel=cfg.job.local_kernel),
-            "alltoall",
-        ),
-    ]
-    ok_all = True
-    try:
-        for label, keys, job, expected in cases:
-            ss_hand = SampleSort(mesh, job)
-            arms = {}
-            for exch in ("alltoall", "ring"):
-                ss_hand.sort(keys, exchange=exch)  # warm/compile
-                times = []
-                for _ in range(args.reps):
-                    t0 = time.perf_counter()
-                    out = ss_hand.sort(keys, exchange=exch)
-                    times.append(time.perf_counter() - t0)
-                arms[exch] = {"dt": float(min(times)), "out": out}
-            # The autotune arm: exchange genuinely unset — the planner's
-            # per-dispatch skew probe decides, and every timed rep
-            # journals its plan_decision with the measured inputs.
-            ss_auto = SampleSort(mesh, dataclasses.replace(job, autotune=True))
-            ss_auto.sort(keys)  # warm/compile (probe runs, unjournaled)
-            start = len(journal)
-            m = Metrics(journal=journal)
-            times = []
-            for _ in range(args.reps):
-                t0 = time.perf_counter()
-                auto_out = ss_auto.sort(keys, metrics=m)
-                times.append(time.perf_counter() - t0)
-            auto_dt = float(min(times))
-            plans = [
-                e for e in journal.events()[start:]
-                if e.type == "plan_decision"
-                and e.fields.get("policy") == "exchange"
-            ]
-            chosen = plans[-1].fields.get("chosen") if plans else None
-            identical = bool(
-                np.array_equal(auto_out, arms["alltoall"]["out"])
-            ) and bool(np.array_equal(auto_out, arms["ring"]["out"]))
-            best_arm = min(arms, key=lambda a: arms[a]["dt"])
-            best_dt = arms[best_arm]["dt"]
-            vs_best = best_dt / auto_dt if auto_dt > 0 else 0.0
-            # The 0.95x floor binds at ladder size (1M+); smoke sizes are
-            # structural-only (see the docstring).
-            fast_enough = vs_best >= 0.95 or args.n < (1 << 20)
-            ok = (
-                identical and chosen == expected and len(plans) == args.reps
-                and fast_enough
-            )
-            ok_all = ok_all and ok
-            n = len(keys)
-            print(json.dumps({
-                "metric": f"autotune_ab_{label}",
-                "value": round(n / auto_dt, 1),
-                "unit": "keys/sec",
-                "chosen_exchange": chosen,
-                "expected_exchange": expected,
-                "best_arm": best_arm,
-                "best_keys_per_sec": round(n / best_dt, 1),
-                "alltoall_keys_per_sec": round(n / arms["alltoall"]["dt"], 1),
-                "ring_keys_per_sec": round(n / arms["ring"]["dt"], 1),
-                "autotune_vs_best": round(vs_best, 3),
-                "plan_decisions": len(plans),
-                "bit_identical": identical,
-            }), flush=True)
-    finally:
-        if getattr(args, "journal", None):
-            journal.flush_jsonl(args.journal)
-    return 0 if ok_all else 1
-
-
-def _queue_fairness(events, tenants) -> tuple[float, float]:
-    """``(p95_wait_s, fairness_p95_ratio)`` from journaled ``job_dequeued``
-    records — THE fairness computation both serving benchmarks share.
-    Big jobs are excluded from the per-tenant comparison: a large job's
-    long wait is its deficit-round-robin cost paying off (it must
-    accumulate the whole mesh), not a tenant being starved."""
-    waits: dict[str, list[float]] = {}
-    all_waits: list[float] = []
-    for e in events:
-        if e.type == "job_dequeued":
-            w = float(e.fields.get("wait_s", 0.0))
-            all_waits.append(w)
-            if not e.fields.get("big"):
-                waits.setdefault(e.fields.get("tenant", "?"), []).append(w)
-    p95 = float(np.percentile(all_waits, 95)) if all_waits else 0.0
-    tenant_p95 = {
-        t: float(np.percentile(ws, 95))
-        for t, ws in waits.items() if t in tenants and ws
-    }
-    fairness = (
-        max(tenant_p95.values()) / max(min(tenant_p95.values()), 1e-9)
-        if len(tenant_p95) > 1 else 1.0
-    )
-    return p95, fairness
-
-
-def _bench_serve_mixed(args, cfg: SortConfig) -> int:
-    """`dsort bench --serve-mixed`: the multi-tenant serving benchmark.
-
-    The `make serve-smoke` target and THE acceptance harness for the
-    serving layer (ARCHITECTURE §8): a mixed workload — 4 small jobs from
-    each of 3 tenants (two repeat sizes, so the compiled-variant cache can
-    prove reuse) plus one large job — submitted concurrently through the
-    real admission queue onto the packed mesh.  Asserts every output
-    bit-identical to ``np.sort`` of its input, then emits ONE JSON line:
-    jobs/s over the mixed workload, p95 queue wait from the journal's
-    ``job_dequeued`` records, per-tenant p95 fairness ratio, compiled-
-    variant cache hit rate, and the packed-vs-serial small-job speedup
-    (the same jobs through a single-slice service).
-    """
-    import dataclasses
-
-    import jax
-
-    from dsort_tpu.data.ingest import gen_uniform
-    from dsort_tpu.obs import Telemetry
-    from dsort_tpu.serve import SortService
-    from dsort_tpu.utils.events import EventLog
-
-    devs = jax.devices()
-    n_devs = cfg.mesh.num_workers or len(devs)
-    if n_devs < 2:
-        raise SystemExit(
-            "--serve-mixed needs a multi-device mesh (packing small jobs "
-            "onto sub-slices of one device is serial dispatch by another "
-            "name); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8"
-        )
-    devs = devs[:n_devs]
-    n_small = max(min(args.n, 1 << 19), 1 << 10)
-    n_large = 1 << 20  # >= FUSED_SMALL_JOB_MAX: routes to the full mesh
-    tenants = ("acme", "blue", "coral")
-    rng = np.random.default_rng(0)
-    # Two repeat sizes per tenant: repeat-size jobs are where the variant
-    # cache must show ≥ 50% hits.
-    small_jobs = []
-    for j in range(4):
-        for t in tenants:
-            n = n_small if j % 2 == 0 else max(n_small // 2, 1 << 9)
-            small_jobs.append(
-                (t, rng.integers(0, 1 << 30, n).astype(np.int32))
-            )
-    large = rng.integers(0, 1 << 30, n_large).astype(np.int32)
-    serve_cfg = dataclasses.replace(
-        cfg.serve,
-        max_queue_depth=max(cfg.serve.max_queue_depth, len(small_jobs) + 4),
-        max_tenant_inflight=max(
-            cfg.serve.max_tenant_inflight, len(small_jobs) + 2
-        ),
-    )
-    journal = _open_journal(args) or EventLog()
-    tel = Telemetry()
-
-    def run_window(svc, jobs, with_large: bool) -> tuple[float, bool]:
-        t0 = time.perf_counter()
-        tickets = [svc.submit(d, tenant=t)[1] for t, d in jobs]
-        if with_large:
-            tickets.append(svc.submit(large, tenant="acme")[1])
-        ok = True
-        for (t, d), ticket in zip(jobs + ([("acme", large)] if with_large else []), tickets):
-            out = ticket.result(timeout=600)
-            ok = ok and bool(np.array_equal(out, np.sort(d)))
-        return time.perf_counter() - t0, ok
-
-    # Serial baseline: the same small jobs through a ONE-slice service
-    # (slice_devices = mesh size), prewarmed like the packed one — the
-    # delta is pure packing, not compiles.
-    sizes = sorted({len(d) for _, d in small_jobs})
-    serial = SortService(
-        devices=devs,
-        job=cfg.job,
-        serve=dataclasses.replace(serve_cfg, slice_devices=n_devs),
-    )
-    serial.prewarm(sizes=sizes)
-    dt_serial, ok_serial = run_window(serial, small_jobs, with_large=False)
-    serial.shutdown()
-
-    svc = SortService(
-        devices=devs, job=cfg.job, serve=serve_cfg, telemetry=tel,
-        journal=journal,
-    )
-    prewarmed = svc.prewarm(sizes=sizes)
-    svc._sched.sort(large)  # warm the full-mesh SPMD program once
-    dt_packed, ok_packed = run_window(svc, small_jobs, with_large=False)
-    mixed_start = len(journal)
-    dt_mixed, ok_mixed = run_window(svc, small_jobs, with_large=True)
-    stats = svc.stats()
-    hit_rate = svc.variants.hit_rate()
-    svc.shutdown()
-    try:
-        if getattr(args, "journal", None):
-            journal.flush_jsonl(args.journal)
-    except OSError as e:
-        log.warning("serve-mixed journal write failed: %s", e)
-    p95, fairness = _queue_fairness(journal.events()[mixed_start:], tenants)
-    ok = ok_serial and ok_packed and ok_mixed
-    jobs_total = len(small_jobs) + 1
-    print(json.dumps({
-        "metric": "service_mixed_workload",
-        "value": round(jobs_total / dt_mixed, 2),
-        "unit": "jobs/sec",
-        "jobs": jobs_total,
-        "tenants": len(tenants),
-        "p95_queue_wait_ms": round(p95 * 1e3, 2),
-        "fairness_p95_ratio": round(fairness, 2),
-        "cache_hit_rate": round(hit_rate, 3),
-        "prewarmed": prewarmed,
-        "speedup_vs_serial": round(dt_serial / dt_packed, 2),
-        "bit_identical": ok,
-        "slices": stats["slices"],
-    }), flush=True)
-    return 0 if ok else 1
-
-
-def _bench_external_wave(args, cfg: SortConfig) -> int:
-    """`dsort bench --external-wave`: the out-of-core wave pipeline bench.
-
-    The `make external-smoke` target and THE acceptance harness for
-    ROADMAP item 2 (ARCHITECTURE §10).  Sorts a binary key file ``W``
-    times larger than the per-wave device budget (``over_hbm_factor`` = W,
-    default 8) through the wave pipeline on the local mesh and emits JSON
-    rows:
-
-    - ``external_wave_sort_uniform_*``: keys/s with the overlap ON,
-      bit-identical to ``np.sort`` of the same data, plus the same-data
-      no-overlap A/B (``overlap_speedup`` = sequential / pipelined — the
-      measured value of overlapping wave k's exchange with wave k-1's
-      spill);
-    - ``external_wave_fault_drill_*``: the same job with a device loss
-      injected inside a middle wave's ring — repaired at run granularity
-      in flight; ``resume_fraction`` (re-sorted runs / total runs) must
-      stay ≤ 1/num_waves + one wave's slack, and the output is still
-      bit-identical.
-    """
-    import tempfile
-
-    import jax
-
-    from dsort_tpu.data.ingest import gen_uniform
-    from dsort_tpu.models.wave_sort import ExternalWaveSort
-    from dsort_tpu.parallel.mesh import local_device_mesh
-    from dsort_tpu.scheduler.fault import WorkerFailure
-
-    mesh = local_device_mesh(cfg.mesh.num_workers)
-    p = int(mesh.shape["w"])
-    if p < 2:
-        raise SystemExit(
-            "--external-wave needs a multi-device mesh (the wave exchange "
-            "is the pipeline under test); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8"
-        )
-    n = max(args.n, 1 << 14)
-    num_waves = 8  # the dataset is 8x the per-wave device budget
-    wave_elems = -(-n // num_waves)
-    journal = _open_journal(args)
-    rows: list[dict] = []
-
-    with tempfile.TemporaryDirectory() as td:
-        in_path = os.path.join(td, "in.bin")
-        data = gen_uniform(n, dtype=np.int32, seed=3)
-        data.tofile(in_path)
-        mm = np.memmap(in_path, dtype=np.int32, mode="r")
-        expect = np.sort(data)
-
-        def run(tag, overlap, fault_wave=None, reps=1):
-            # ONE sorter per mode: its compiled plan/ring programs persist
-            # across reps (instance-level caches), so min-of-reps times the
-            # pipeline, not the compiler.
-            s = ExternalWaveSort(
-                mesh, wave_elems=wave_elems,
-                spill_dir=os.path.join(td, "spill"),
-                job_id=f"bench_{tag}", resume=False, overlap=overlap,
-            )
-            if fault_wave is not None:
-                calls = {"n": 0}
-
-                def hook():
-                    calls["n"] += 1
-                    if calls["n"] == fault_wave + 1:
-                        raise WorkerFailure(
-                            "injected mid-ring device loss (bench drill)"
-                        )
-
-                s.fault_hook = hook
-            best, counters, all_ok = None, None, True
-            for _ in range(reps):
-                m = Metrics(journal=journal)
-                out = np.empty(n, np.int32)
-                t0 = time.perf_counter()
-                s.sort(mm, out=out, metrics=m)
-                dt = time.perf_counter() - t0
-                # EVERY rep must be bit-identical — a wrong fast rep must
-                # fail the row, not hide behind a correct slower one.
-                all_ok = all_ok and bool(np.array_equal(out, expect))
-                if best is None or dt < best:
-                    best = dt
-                counters = dict(m.counters)
-            return best, all_ok, counters
-
-        # Warm the shared-input page cache + one compile set off the clock.
-        run("warm", overlap=True)
-        dt_seq, ok_seq, _ = run("seq", overlap=False, reps=args.reps)
-        dt_pipe, ok_pipe, c_pipe = run("pipe", overlap=True, reps=args.reps)
-        total_runs = num_waves * p
-        rows.append({
-            "metric": f"external_wave_sort_uniform_{_nlabel(n)}",
-            "value": round(n / dt_pipe, 1),
-            "unit": "keys/sec",
-            "bit_identical": bool(ok_pipe and ok_seq),
-            "over_hbm_factor": num_waves,
-            "num_waves": num_waves,
-            "overlap_speedup": round(dt_seq / dt_pipe, 3),
-            "resume_fraction": 0.0,
-            "bytes_on_wire": int(c_pipe.get("exchange_bytes_on_wire", 0)),
-            "exchange": "ring",
-        })
-        dt_f, ok_f, c_f = run("fault", overlap=True, fault_wave=num_waves // 2)
-        resorted = int(c_f.get("wave_runs_resorted", 0))
-        frac = resorted / total_runs
-        rows.append({
-            "metric": f"external_wave_fault_drill_{_nlabel(n)}",
-            "value": round(n / dt_f, 1),
-            "unit": "keys/sec",
-            "bit_identical": bool(ok_f),
-            "over_hbm_factor": num_waves,
-            "num_waves": num_waves,
-            "runs_resorted": resorted,
-            "resume_fraction": round(frac, 4),
-            "exchange": "ring",
-        })
-        ok = (
-            ok_seq and ok_pipe and ok_f
-            and 0 < resorted
-            and frac <= 1.0 / num_waves + 1.0 / total_runs
-        )
-    for row in rows:
-        print(json.dumps(row), flush=True)
-    if journal is not None:
-        journal.flush_jsonl(args.journal)
-    return 0 if ok else 1
-
-
-def _nlabel(n: int) -> str:
-    return f"{n >> 20}M" if n % (1 << 20) == 0 and n >= (1 << 20) else f"{n}_keys"
-
-
-def _bench_analyze_smoke(args, cfg: SortConfig) -> int:
-    """`dsort bench --analyze-smoke`: the introspection plane's own cost.
-
-    The `make profile-smoke` target (tier-1-gated like the other smokes).
-    Runs the same ring sort with and without the full introspection stack
-    attached — journal, compile ledger drain, memwatch tap — and emits ONE
-    JSON line whose ``overhead_frac`` is the measured cost of observing
-    (< 5% is the contract, the row's exit code enforces it).  The same
-    run also exercises the analyzer end to end: a zipf ring run's journal
-    must yield a skew ratio measurably above the uniform run's, and the
-    verdict's dominant phase and compile split ride along in the row.
-    """
-    from dsort_tpu.config import JobConfig
-    from dsort_tpu.data.ingest import gen_uniform, gen_zipf
-    from dsort_tpu.obs.analyze import analyze_records
-    from dsort_tpu.obs.prof import MemWatch
-    from dsort_tpu.parallel.mesh import local_device_mesh
-    from dsort_tpu.parallel.sample_sort import SampleSort
-    from dsort_tpu.utils.events import EventLog
-
-    mesh = local_device_mesh(cfg.mesh.num_workers)
-    if mesh.shape["w"] < 2:
-        raise SystemExit(
-            "--analyze-smoke needs a multi-worker mesh (the skew report "
-            "rides the ring plan); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8"
-        )
-    ss = SampleSort(
-        mesh, JobConfig(key_dtype=np.int64, local_kernel=cfg.job.local_kernel)
-    )
-    n = args.n
-    uni = gen_uniform(n, dtype=np.int64, seed=0)
-    zipf = gen_zipf(n, a=1.3, seed=4)
-
-    def timed(data, journal=None, memwatch=False):
-        times, log_ = [], journal
-        for _ in range(args.reps):
-            m = Metrics(journal=log_)
-            if memwatch:
-                MemWatch().attach(m)
-            t0 = time.perf_counter()
-            ss.sort(data, metrics=m, exchange="ring")
-            times.append(time.perf_counter() - t0)
-        return float(min(times))  # one-sided jitter doctrine
-
-    ss.sort(uni, exchange="ring")   # warm/compile both plans
-    ss.sort(zipf, exchange="ring")
-    bare_s = timed(uni)
-    uni_journal = EventLog()
-    # The overhead under test is the ALWAYS-ON plane: journal + compile
-    # ledger.  The memwatch tap is an opt-in flag (each snapshot walks the
-    # backend's live allocations — worth paying when hunting HBM, not a
-    # tax every job should carry), so it rides the verdict-exercise run
-    # below, outside the timed A/B.
-    obs_s = timed(uni, journal=uni_journal)
-    overhead = (obs_s - bare_s) / bare_s
-    zipf_journal = EventLog()
-    mz = Metrics(journal=zipf_journal)
-    MemWatch().attach(mz)
-    ss.sort(zipf, metrics=mz, exchange="ring")
-    vz = analyze_records([e.to_dict() for e in zipf_journal.events()])
-    vu = analyze_records([e.to_dict() for e in uni_journal.events()])
-    skew_z = (vz.get("skew") or {}).get("max_mean_ratio", 0.0)
-    skew_u = (vu.get("skew") or {}).get("max_mean_ratio", 0.0)
-    if getattr(args, "journal", None):
-        zipf_journal.flush_jsonl(args.journal)
-    # The < 5% contract binds at the 1M row (BENCH_r09.jsonl); below it a
-    # single sort is fast enough that scheduler jitter, not the journal,
-    # dominates the A/B — the small-n gate checks the plane end to end,
-    # the big-n run checks its price.
-    overhead_ok = overhead < 0.05 or n < (1 << 20)
-    ok = overhead_ok and skew_u > 0 and skew_z > skew_u
-    print(json.dumps({
-        "metric": (
-            "analyze_overhead_1M" if n == 1 << 20
-            else f"analyze_overhead_{n}_keys"
-        ),
-        "value": round(max(overhead, 0.0), 4),
-        "unit": "frac",
-        "overhead_frac": round(overhead, 4),
-        "bare_keys_per_sec": round(n / bare_s, 1),
-        "journaled_keys_per_sec": round(n / obs_s, 1),
-        "dominant_phase": str(vz.get("dominant_phase")),
-        "skew_ratio_zipf": round(skew_z, 3),
-        "skew_ratio_uniform": round(skew_u, 3),
-        "hbm_watermark_bytes": int((vz.get("hbm") or {}).get("bytes_in_use", 0)),
-        "introspection_ok": ok,
-    }), flush=True)
-    return 0 if ok else 1
-
-
-def _bench_fleet_mixed(args, cfg: SortConfig) -> int:
-    """`dsort bench --fleet-mixed`: the federated serving benchmark.
-
-    The `make fleet-smoke` target and THE acceptance harness for the
-    fleet plane (ARCHITECTURE §12): TWO local execution agents — each a
-    real `FleetAgent` over its own half of the device mesh, spoken to
-    over real TCP — behind a `FleetController`, driven with a mixed
-    workload (4 small jobs x 3 tenants at two repeat sizes, twice, plus
-    one large full-mesh job) under BOTH routing policies.  The A/B axis
-    is variant-cache locality: under ``routing="locality"`` repeat-size
-    jobs stick to the agent that already compiled their ladder rung,
-    under ``routing="random"`` they scatter and both agents pay the
-    compile — the row carries both fleet-wide hit rates and exits
-    nonzero unless locality wins AND every output is bit-identical to
-    ``np.sort``.  Fairness (p95 queue-wait ratio across tenants, from
-    the controller journal's ``job_dequeued`` records) must hold the
-    same 3x bound the PR 7 serving layer is tested to.
-
-    ISSUE 14 adds two arms: a heartbeats-only locality baseline (health
-    telemetry off) whose elapsed-time ratio against the locality arm is
-    ``telemetry_overhead_frac`` (the <5% live-telemetry contract), and a
-    ``routing="health"`` arm emitting its own
-    ``fleet_mixed_health_routing_2agents`` row (rolling verdict count,
-    hit rate, speedup vs locality) — the drilled route-around-a-straggler
-    behavior lives in ``tests/test_health.py``.
-    """
-    import dataclasses
-    import tempfile
-
-    import jax
-
-    from dsort_tpu.fleet.agent import FleetAgent
-    from dsort_tpu.fleet.controller import FleetController
-    from dsort_tpu.serve import SortService
-    from dsort_tpu.utils.events import EventLog
-
-    devs = jax.devices()
-    n_devs = cfg.mesh.num_workers or len(devs)
-    if n_devs < 2:
-        raise SystemExit(
-            "--fleet-mixed needs >= 2 devices (each agent owns half the "
-            "mesh); run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8"
-        )
-    devs = devs[:n_devs]
-    half = max(n_devs // 2, 1)
-    n_small = max(min(args.n, 1 << 19), 1 << 10)
-    n_large = 1 << 20  # >= FLEET_SMALL_JOB_MAX: routes by size
-    tenants = ("acme", "blue", "coral")
-    rng = np.random.default_rng(0)
-    # Two repeat sizes x two rounds: repeat-size jobs are where locality
-    # routing must show its cache-hit advantage over random.
-    small_jobs = []
-    for rnd in range(2):
-        for j in range(4):
-            for t in tenants:
-                n = n_small if j % 2 == 0 else max(n_small // 2, 1 << 9)
-                small_jobs.append(
-                    (t, rng.integers(0, 1 << 30, n).astype(np.int32))
-                )
-    large = rng.integers(0, 1 << 30, n_large).astype(np.int32)
-    serve_cfg = dataclasses.replace(
-        cfg.serve,
-        max_queue_depth=max(cfg.serve.max_queue_depth, len(small_jobs) + 8),
-        max_tenant_inflight=max(
-            cfg.serve.max_tenant_inflight, len(small_jobs) + 2
-        ),
-    )
-    journal = _open_journal(args) or EventLog()
-
-    def run_arm(routing: str, arm_journal, td: str, name: str,
-                telemetry_on: bool = True):
-        agents = [
-            FleetAgent(
-                service=SortService(
-                    devices=devs[:half], job=cfg.job, serve=serve_cfg
-                ),
-                agent_id=f"{name}-a",
-            ),
-            FleetAgent(
-                service=SortService(
-                    devices=devs[half:], job=cfg.job, serve=serve_cfg
-                ),
-                agent_id=f"{name}-b",
-            ),
-        ]
-        ctl = FleetController(
-            [ag.addr for ag in agents],
-            state_dir=os.path.join(td, name),
-            max_queue_depth=serve_cfg.max_queue_depth,
-            max_tenant_inflight=serve_cfg.max_tenant_inflight,
-            routing=routing,
-            heartbeat_s=0.5,
-            journal=arm_journal,
-            health_telemetry=telemetry_on,
-        )
-        try:
-            t0 = time.perf_counter()
-            tickets = [
-                ctl.submit(d, tenant=t)[1] for t, d in small_jobs
-            ]
-            tickets.append(ctl.submit(large, tenant="acme")[1])
-            ok = True
-            for (t, d), ticket in zip(
-                small_jobs + [("acme", large)], tickets
-            ):
-                out = ticket.result(timeout=900)
-                ok = ok and bool(np.array_equal(out, np.sort(d)))
-            dt = time.perf_counter() - t0
-            rerouted = sum(
-                1 for e in arm_journal.events() if e.type == "job_rerouted"
-            )
-            hits = misses = 0
-            for ag in agents:
-                st = ag.service.variants.stats()
-                hits += st["hits"]
-                misses += st["misses"]
-            hit_rate = hits / (hits + misses) if (hits + misses) else 0.0
-            verdicts = sum(
-                1 for e in arm_journal.events() if e.type == "health_verdict"
-            )
-            return dt, ok, hit_rate, rerouted, verdicts
-        finally:
-            ctl.shutdown(drain=True)
-            for ag in agents:
-                ag.close()
-
-    rand_journal, health_journal = EventLog(), EventLog()
-    reps = max(getattr(args, "reps", 1), 1)
-    with tempfile.TemporaryDirectory() as td:
-        # The random arm runs FIRST and warms process-wide compile caches,
-        # so the arms after it compare on an equal (warm) footing — in
-        # particular the telemetry-overhead pair below.
-        dt_rand, ok_rand, hit_rand, _, _ = run_arm(
-            "random", rand_journal, td, "random"
-        )
-        # Heartbeats-only baseline vs the live health plane: identical
-        # locality workload, telemetry opt-in the ONLY difference — the
-        # ratio is the overhead the <5% contract binds on.  Min-of-reps
-        # on BOTH sides (the bench doctrine): the per-frame work is tiny
-        # and a single elapsed sample is scheduler-noise-dominated.
-        dt_hb = dt_loc = None
-        ok_hb = ok_loc = True
-        for i in range(reps):
-            dt, ok, _, _, _ = run_arm(
-                "locality", EventLog(), td, f"hb-only{i}",
-                telemetry_on=False,
-            )
-            ok_hb = ok_hb and ok
-            dt_hb = dt if dt_hb is None else min(dt_hb, dt)
-            dt, ok, hit_loc, rerouted, _ = run_arm(
-                "locality", journal, td, f"locality{i}"
-            )
-            ok_loc = ok_loc and ok
-            dt_loc = dt if dt_loc is None else min(dt_loc, dt)
-        dt_health, ok_health, hit_health, _, verdicts = run_arm(
-            "health", health_journal, td, "health"
-        )
-    try:
-        if getattr(args, "journal", None):
-            journal.flush_jsonl(args.journal)
-    except OSError as e:
-        log.warning("fleet-mixed journal write failed: %s", e)
-    p95, fairness = _queue_fairness(journal.events(), tenants)
-    ok = (
-        ok_rand and ok_loc and ok_hb and ok_health and hit_loc > hit_rand
-        and verdicts > 0
-    )
-    jobs_total = len(small_jobs) + 1
-    print(json.dumps({
-        "metric": "fleet_mixed_workload_2agents",
-        "value": round(jobs_total / dt_loc, 2),
-        "unit": "jobs/sec",
-        "jobs": jobs_total,
-        "tenants": len(tenants),
-        "agents": 2,
-        "cache_hit_rate": round(hit_loc, 3),
-        "cache_hit_rate_random": round(hit_rand, 3),
-        "p95_queue_wait_ms": round(p95 * 1e3, 2),
-        "fairness_p95_ratio": round(fairness, 2),
-        "speedup_vs_random": round(dt_rand / dt_loc, 2),
-        "rerouted": rerouted,
-        "telemetry_overhead_frac": round(dt_loc / dt_hb - 1.0, 4),
-        "bit_identical": ok_rand and ok_loc,
-    }), flush=True)
-    print(json.dumps({
-        "metric": "fleet_mixed_health_routing_2agents",
-        "value": round(jobs_total / dt_health, 2),
-        "unit": "jobs/sec",
-        "jobs": jobs_total,
-        "tenants": len(tenants),
-        "agents": 2,
-        "cache_hit_rate": round(hit_health, 3),
-        "health_verdicts": verdicts,
-        "speedup_vs_locality": round(dt_loc / dt_health, 2),
-        "bit_identical": ok_health,
-    }), flush=True)
-    return 0 if ok else 1
-
-
-def cmd_bench(args) -> int:
-    from dsort_tpu.data.ingest import gen_uniform
-
-    if args.reps < 1:
-        raise SystemExit("--reps must be >= 1")
-    if getattr(args, "coded_v2_ab", False):
-        if args.suite or getattr(args, "device_resident", False) or getattr(
-            args, "exchange_ab", False
-        ) or getattr(args, "serve_mixed", False) or getattr(
-            args, "analyze_smoke", False
-        ) or getattr(args, "external_wave", False) or getattr(
-            args, "fleet_mixed", False
-        ) or getattr(args, "coded_ab", False) or getattr(
-            args, "autotune_ab", False
-        ) or getattr(args, "hier_ab", False):
-            raise SystemExit(
-                "--coded-v2-ab is its own benchmark: run it as a separate "
-                "invocation"
-            )
-        return _bench_coded_v2_ab(args, _load_config(args))
-    if getattr(args, "hier_ab", False):
-        if args.suite or getattr(args, "device_resident", False) or getattr(
-            args, "exchange_ab", False
-        ) or getattr(args, "serve_mixed", False) or getattr(
-            args, "analyze_smoke", False
-        ) or getattr(args, "external_wave", False) or getattr(
-            args, "fleet_mixed", False
-        ) or getattr(args, "coded_ab", False) or getattr(
-            args, "autotune_ab", False
-        ):
-            raise SystemExit(
-                "--hier-ab is its own benchmark: run it as a separate "
-                "invocation"
-            )
-        return _bench_hier_ab(args, _load_config(args))
-    if getattr(args, "autotune_ab", False):
-        if args.suite or getattr(args, "device_resident", False) or getattr(
-            args, "exchange_ab", False
-        ) or getattr(args, "serve_mixed", False) or getattr(
-            args, "analyze_smoke", False
-        ) or getattr(args, "external_wave", False) or getattr(
-            args, "fleet_mixed", False
-        ) or getattr(args, "coded_ab", False):
-            raise SystemExit(
-                "--autotune-ab is its own benchmark: run it as a separate "
-                "invocation"
-            )
-        return _bench_autotune_ab(args, _load_config(args))
-    if getattr(args, "coded_ab", False):
-        if args.suite or getattr(args, "device_resident", False) or getattr(
-            args, "exchange_ab", False
-        ) or getattr(args, "serve_mixed", False) or getattr(
-            args, "analyze_smoke", False
-        ) or getattr(args, "external_wave", False) or getattr(
-            args, "fleet_mixed", False
-        ):
-            raise SystemExit(
-                "--coded-ab is its own benchmark: run it as a separate "
-                "invocation"
-            )
-        return _bench_coded_ab(args, _load_config(args))
-    if getattr(args, "fleet_mixed", False):
-        if args.suite or getattr(args, "device_resident", False) or getattr(
-            args, "exchange_ab", False
-        ) or getattr(args, "serve_mixed", False) or getattr(
-            args, "analyze_smoke", False
-        ) or getattr(args, "external_wave", False):
-            raise SystemExit(
-                "--fleet-mixed is its own benchmark: run it as a separate "
-                "invocation"
-            )
-        return _bench_fleet_mixed(args, _load_config(args))
-    if getattr(args, "external_wave", False):
-        if args.suite or getattr(args, "device_resident", False) or getattr(
-            args, "exchange_ab", False
-        ) or getattr(args, "serve_mixed", False) or getattr(
-            args, "analyze_smoke", False
-        ):
-            raise SystemExit(
-                "--external-wave is its own benchmark: run it as a "
-                "separate invocation"
-            )
-        return _bench_external_wave(args, _load_config(args))
-    if getattr(args, "analyze_smoke", False):
-        if args.suite or getattr(args, "device_resident", False) or getattr(
-            args, "exchange_ab", False
-        ) or getattr(args, "serve_mixed", False):
-            raise SystemExit(
-                "--analyze-smoke is its own benchmark: run it as a "
-                "separate invocation"
-            )
-        return _bench_analyze_smoke(args, _load_config(args))
-    if getattr(args, "serve_mixed", False):
-        if args.suite or getattr(args, "device_resident", False) or getattr(
-            args, "exchange_ab", False
-        ):
-            raise SystemExit(
-                "--serve-mixed is its own benchmark: run it as a separate "
-                "invocation"
-            )
-        return _bench_serve_mixed(args, _load_config(args))
-    if getattr(args, "exchange_ab", False):
-        if args.suite or getattr(args, "device_resident", False):
-            raise SystemExit(
-                "--exchange-ab is its own benchmark: run it as a separate "
-                "invocation"
-            )
-        return _bench_exchange_ab(args, _load_config(args))
-    if args.suite and getattr(args, "device_resident", False):
-        # The ladder has its own metric contract; silently dropping one of
-        # two explicit flags would ship an artifact missing the lines the
-        # user asked for.
-        raise SystemExit(
-            "--suite and --device-resident are separate benchmarks: run "
-            "them as two invocations"
-        )
-    if args.suite:
-        return _bench_suite(args)
-    cfg = _load_config(args)
-    if getattr(args, "device_resident", False):
-        if args.mode != "spmd":
-            raise SystemExit("--device-resident requires --mode spmd")
-        return _bench_device_resident(args, cfg)
-    sorter = _make_sorter(cfg, args.mode)
-    data = gen_uniform(args.n, dtype=np.dtype(cfg.job.key_dtype), seed=0)
-    journal = _open_journal(args)
-    sorter(data, Metrics())  # warm/compile
-    times = []
-    try:
-        for _ in range(args.reps):
-            m = Metrics(journal=journal)
-            _maybe_memwatch(args, m)
-            t0 = time.perf_counter()
-            sorter(data, m)
-            times.append(time.perf_counter() - t0)
-    finally:
-        # Same discipline as run/serve/batch: a rep that crashes must not
-        # lose the journal of the reps that did complete.
-        _write_journal(journal, args)
-    dt = float(min(times))  # one-sided dispatch jitter; see _bench_suite
-    print(
-        json.dumps(
-            {
-                "metric": f"sort_throughput_{np.dtype(cfg.job.key_dtype)}_{args.n}_keys_{args.mode}",
-                "value": round(args.n / dt, 1),
-                "unit": "keys/sec",
-                "vs_baseline": round(args.n / dt / _REF_KEYS_PER_SEC, 2),
-            }
-        )
-    )
-    return 0
 
 
 def cmd_batch(args) -> int:
@@ -3558,78 +1798,6 @@ def main(argv=None) -> int:
                    help="admission shedding target (ms, per-tenant live "
                         "p95 queue wait)")
     p.set_defaults(fn=cmd_fleet)
-
-    p = sub.add_parser("bench", help="throughput benchmark (one JSON line)")
-    common(p)
-    p.add_argument("--n", type=int, default=1 << 22)
-    p.add_argument("--reps", type=int, default=3)
-    p.add_argument("--suite", action="store_true",
-                   help="run the BASELINE config ladder (one JSON line each)")
-    p.add_argument("--device-resident", action="store_true",
-                   help="time the no-relay path: device-resident sort + "
-                        "on-device validation, one JSON line each")
-    p.add_argument("--exchange-ab", action="store_true",
-                   help="three-way alltoall/ring/fused exchange A/B on the "
-                        "local mesh "
-                        "(uniform + zipf; asserts bit-identical outputs, "
-                        "reports bytes_on_wire per schedule)")
-    p.add_argument("--serve-mixed", action="store_true",
-                   help="multi-tenant serving benchmark: a mixed small/large "
-                        "three-tenant workload through the real admission "
-                        "queue with mesh-slice packing; one JSON line with "
-                        "jobs/s, p95 queue wait, fairness ratio, variant-"
-                        "cache hit rate and packed-vs-serial speedup")
-    p.add_argument("--analyze-smoke", action="store_true",
-                   help="introspection-plane cost proof: the same ring "
-                        "sort with and without journal+ledger+memwatch "
-                        "attached (overhead_frac < 5%% is the contract), "
-                        "plus the zipf-vs-uniform skew report margin")
-    p.add_argument("--memwatch", action="store_true",
-                   help="snapshot device memory at phase boundaries into "
-                        "hbm_watermark journal events")
-    p.add_argument("--fleet-mixed", action="store_true",
-                   help="federated serving benchmark: 2 local mesh-owning "
-                        "agents behind a fleet controller over real TCP, "
-                        "mixed tenants/sizes, locality-vs-random routing "
-                        "A/B; one JSON line with both fleet-wide variant-"
-                        "cache hit rates, fairness ratio and bit-identical "
-                        "outputs")
-    p.add_argument("--coded-ab", action="store_true",
-                   help="coded-redundancy failure A/B: the same zipf "
-                        "workload at redundancy=1 vs 2, healthy vs one "
-                        "injected device loss (bit-identical gate); JSON "
-                        "rows with throughput_under_failure_ratio and the "
-                        "healthy-path replica overhead")
-    p.add_argument("--coded-v2-ab", action="store_true",
-                   help="coded-exchange v2 acceptance A/B (ARCHITECTURE "
-                        "§18): replicate vs parity at redundancy=2 — "
-                        "healthy wire premium (parity < 0.75x replicate's "
-                        "coded_replica_bytes), one injected loss per mode "
-                        "(both recover locally, zero re-sorted keys), and "
-                        "the straggler drill (p99 with serving ON beats "
-                        "the measured wait-on-owner baseline, exactly one "
-                        "serve per rep); bit-identical gate throughout")
-    p.add_argument("--autotune-ab", action="store_true",
-                   help="closed-loop planner A/B: zipf + uniform workloads "
-                        "with exchange hand-set to alltoall, hand-set to "
-                        "ring, and planner-chosen (autotune on, knob "
-                        "unset); gates bit-identical outputs, the measured-"
-                        "skew pick (ring on zipf, alltoall on uniform) and "
-                        "autotune >= 0.95x the best hand-set arm at 1M+")
-    p.add_argument("--hier-ab", action="store_true",
-                   help="two-level pod exchange A/B: flat ring vs hier at "
-                        "every simulated HxD topology the mesh divides "
-                        "into, plus the device-loss and host-loss drills "
-                        "(bit-identical gate; gates measured DCN-leg byte "
-                        "reduction and the survivors' (H',H') re-plan)")
-    p.add_argument("--external-wave", action="store_true",
-                   help="out-of-core wave-pipeline benchmark: sort a "
-                        "dataset 8x the per-wave device budget through the "
-                        "mesh wave pipeline (overlap-on vs overlap-off A/B "
-                        "+ a mid-wave fault drill with run-granular "
-                        "resume); JSON rows with over_hbm_factor and "
-                        "resume_fraction")
-    p.set_defaults(fn=cmd_bench)
 
     p = sub.add_parser(
         "batch", help="sort many files as ONE batched SPMD program (dp axis)"

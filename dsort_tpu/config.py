@@ -407,8 +407,7 @@ class FleetConfig:
     fails it over (``FLEET_DISPATCH_TIMEOUT_S``; None = the controller's
     request timeout); ``telemetry`` opts agents into the health plane's
     bounded delta stream on the heartbeat cadence (``FLEET_TELEMETRY``;
-    on by default — off = heartbeats-only, the bench's overhead
-    baseline).
+    on by default — off = heartbeats-only).
     """
 
     agents: tuple[str, ...] = ()

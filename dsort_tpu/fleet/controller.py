@@ -16,7 +16,7 @@ in heartbeats, the controller computes the job's capacity-ladder rung with
 the pure twin `proto.fused_rung`, and a job whose rung is already compiled
 on mesh B prefers mesh B (a sticky affinity map makes the preference
 deterministic even before the first heartbeat refresh).  `routing=
-"random"` is the A/B baseline (`dsort bench --fleet-mixed`); `routing=
+"random"` is the A/B baseline locality is tested against; `routing=
 "health"` keeps the locality arm for small jobs but places BIG jobs on
 the mesh whose measured straggler profile is cleanest — agents stream
 bounded `telemetry` deltas on the heartbeat cadence, `obs.health.

@@ -227,7 +227,7 @@ def _run_wave_pipeline(
     the mesh runs wave ``k``, and wave ``k-1`` retires (fetch + spill +
     host run-merge) — its checkpoint writes ride a writer thread, surfaced
     in order like `_overlapped_run_generation`.  ``overlap=False`` is the
-    strict sequential schedule (the A/B baseline of the bench row).
+    strict sequential schedule, with the same output.
 
     A recoverable device fault in a wave's dispatch or retire re-sorts that
     wave's runs on the host (``repair`` — the input slice is still
@@ -309,7 +309,7 @@ class ExternalWaveSort:
     a dataset ``W`` times larger runs as ``W`` pipelined waves.
     ``spill_dir``/``job_id``/``resume``: the `ShardCheckpoint` (wave, run)
     store and its resume key.  ``overlap=False`` disables the pipeline
-    (the bench A/B baseline).  ``exchange`` ("ring" | "fused", default
+    (same output, sequential).  ``exchange`` ("ring" | "fused", default
     `JobConfig.exchange` via the shared resolver): "fused" runs each
     wave's exchange+merge as ONE Pallas kernel (`ops.ring_kernel`) — the
     wave never leaves the device between partition and spill.

@@ -199,8 +199,8 @@ def analyze_records(
         elif etype == "job_evicted":
             evictions += 1
         elif etype == "wave_start":
-            # Scoped by job ordinal: a session journal (the external-smoke
-            # bench, a serve loop) holds MANY wave jobs, and wave ids
+            # Scoped by job ordinal: a session journal (a serve loop, a
+            # process sorting many files) holds MANY wave jobs, and wave ids
             # repeat per job — an unscoped key would pair one job's start
             # with another's done.
             wave_start.setdefault((src, r.get("job"), r.get("wave")), r["mono"])

@@ -5,7 +5,7 @@ mid-session) and IDENTICALLY post-hoc from a journal — so both paths share
 this one deterministic structure instead of keeping raw samples: fixed
 bucket bounds mean a scrape and a journal replay that saw the same
 durations report byte-identical quantiles, which is exactly what the
-serve-smoke gate asserts.
+serve metrics-endpoint test asserts.
 """
 
 from __future__ import annotations

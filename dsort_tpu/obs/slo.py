@@ -12,8 +12,8 @@ derivation (`_JobState.durations`) backs both consumers:
   `LatencyHistogram` set the metrics endpoint snapshots;
 - POST-HOC: `slo_from_journal` replays a journal's records through the
   identical state machine, so a scrape and a journal replay of the same
-  session report byte-identical quantiles — the property the serve-smoke
-  gate asserts.
+  session report byte-identical quantiles — the property the serve
+  metrics-endpoint test asserts.
 
 The ``tenant`` label rides the ``job_start`` event (threaded from
 ``JobConfig.tenant``); jobs in an interleaved journal are told apart by the

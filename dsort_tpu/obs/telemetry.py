@@ -9,8 +9,8 @@ with the journal's own timestamps.
 
 The snapshot renders in the Prometheus text exposition format (0.0.4) so
 any scraper — or the in-tree minimal parser `parse_prometheus_text`, which
-the tier-1 serve-smoke gate round-trips through — can consume it; the
-stdlib HTTP endpoint lives in `obs.server`, the console view in
+the tier-1 serve metrics-endpoint test round-trips through — can consume
+it; the stdlib HTTP endpoint lives in `obs.server`, the console view in
 ``dsort top``.
 """
 

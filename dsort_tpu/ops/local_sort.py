@@ -129,8 +129,8 @@ def sort_with_kernel(keys: jax.Array, kernel: str = "auto") -> jax.Array:
       small arrays) — see `resolve_kernel`;
     - ``lax``: XLA's built-in sort (safe everywhere);
     - ``block``: the fused block-bitonic Pallas kernel (``ops.block_sort``) —
-      the fastest single-chip kernel (bench-recorded 1.52 Gkeys/s vs lax's
-      0.85 Gkeys/s at 2^24 int32 on TPU v5e, and no 2^26 cliff);
+      the fastest single-chip kernel, with no 2^26 cliff (chip numbers:
+      PERF.md);
     - ``bitonic``: the pure-jnp vectorized bitonic network (``ops.bitonic``);
     - ``pallas``: the Pallas VMEM tile-sort kernel (``ops.pallas_sort``);
     - ``radix``: the stable LSD counting-sort radix (``ops.radix``).

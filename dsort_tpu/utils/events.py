@@ -204,10 +204,9 @@ EVENT_TYPES: dict[str, str] = {
 }
 
 #: THE counter registry: every `Metrics.bump` name in the package, with its
-#: meaning.  The journal (``job_done`` carries the final counters), bench
-#: artifact lines, and README's Observability section all share this one
-#: vocabulary; ``tests/test_events.py`` greps the source tree to keep it
-#: exhaustive.
+#: meaning.  The journal (``job_done`` carries the final counters) and
+#: README's Observability section share this one vocabulary;
+#: ``tests/test_events.py`` greps the source tree to keep it exhaustive.
 COUNTERS: dict[str, str] = {
     "reassignments": "shards moved to another worker after a failure",
     "heartbeat_timeouts": "taskpool attempts abandoned on a lapsed wait",

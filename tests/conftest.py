@@ -46,3 +46,16 @@ def mesh8(devices):
     from dsort_tpu.parallel.mesh import local_device_mesh
 
     return local_device_mesh(8)
+
+
+@pytest.fixture
+def fresh_compile_ledger():
+    """Empty the process-wide compile ledger before the test.
+
+    Fleet agents advertise the ledger's labels for locality routing, so
+    variants compiled by earlier tests in the same process would steer
+    where a job lands.  Modules that build ``FleetAgent``s apply this to
+    every test with ``pytestmark``."""
+    from dsort_tpu.obs.prof import LEDGER
+
+    LEDGER.reset()

@@ -85,7 +85,7 @@ def test_from_mapping_rejects_zero_values():
 
 @pytest.mark.parametrize("env_dir", [None, "from_env"])
 def test_compile_cache_placement(env_dir, tmp_path, monkeypatch):
-    """`use_compile_cache` (called by `dsort`, bench.py and chip_smoke.py):
+    """`use_compile_cache` (called by `dsort` and chip_smoke.py):
     a set ``JAX_COMPILATION_CACHE_DIR`` is JAX's to read and nothing is set
     in code; unset, the cache is the checkout's fixed ``.jax_cache``."""
     import os

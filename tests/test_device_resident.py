@@ -295,32 +295,7 @@ def test_spmd_scheduler_device_resident_float_refusal():
         )
 
 
-# ---- the `make bench-smoke` tier-1 gate -----------------------------------
-
-
-def test_cli_bench_smoke_device_resident(tmp_path, capsys):
-    """The bench-smoke path (`dsort bench --device-resident --journal`):
-    emits the sort_e2e_device_resident_* and validate lines, exits 0, and
-    journals the device-handle/validate events."""
-    from dsort_tpu import cli
-
-    journal = tmp_path / "smoke.jsonl"
-    rc = cli.main([
-        "bench", "--device-resident", "--n", "50000", "--reps", "1",
-        "--journal", str(journal),
-    ])
-    assert rc == 0
-    out_lines = [
-        json.loads(ln) for ln in capsys.readouterr().out.splitlines() if ln
-    ]
-    metrics = {ln["metric"]: ln for ln in out_lines}
-    e2e = [m for m in metrics if m.startswith("sort_e2e_device_resident_")]
-    val = [m for m in metrics if m.startswith("device_validate_")]
-    assert e2e and val
-    assert metrics[e2e[0]]["value"] > 0
-    assert metrics[val[0]]["validated_ok"] is True
-    types = [r["type"] for r in EventLog.read_jsonl(str(journal))]
-    assert "device_handle" in types and "device_validate" in types
+# ---- the CLI --------------------------------------------------------------
 
 
 def test_cli_run_device_resident(tmp_path):

@@ -122,7 +122,7 @@ def test_format_report_tables():
 
 def test_counter_registry_is_exhaustive():
     """Every `Metrics.bump` name in the package is a documented counter —
-    the registry (shared by journal, bench, README) cannot drift."""
+    the registry (shared by journal and README) cannot drift."""
     root = os.path.join(os.path.dirname(os.path.dirname(__file__)), "dsort_tpu")
     bumped = set()
     for dirpath, _, names in os.walk(root):

@@ -20,6 +20,7 @@ from dsort_tpu.config import ConfigError, JobConfig
 from dsort_tpu.data.ingest import gen_terasort, gen_uniform, gen_zipf
 from dsort_tpu.parallel.exchange import (
     alltoall_wire_bytes,
+    dispatches_per_exchange,
     ring_caps,
     ring_step_quantum,
     ring_wire_bytes,
@@ -377,43 +378,48 @@ def test_ring_via_scheduler_checkpoint_path(tmp_path):
     assert "exchange_ring_steps" not in m2.counters
 
 
-# ---- the `make bench-exchange-smoke` tier-1 gate --------------------------
+# ---- every schedule on every workload -------------------------------------
+
+SCHEDULE_WORKLOADS = ["uniform_int32", "zipf_int32", "uniform_int64", "kv_records"]
 
 
-def test_cli_bench_exchange_ab(tmp_path, capsys):
-    """The bench-exchange-smoke path (`dsort bench --exchange-ab`): one
-    ring-vs-alltoall row per workload, bit-identical asserted, wire bytes
-    measurably below the padded path on the skewed case, exchange events
-    journaled, exit 0."""
-    import json
-
-    from dsort_tpu import cli
-
-    journal = tmp_path / "exchange.jsonl"
-    rc = cli.main([
-        "bench", "--exchange-ab", "--n", "100000", "--reps", "1",
-        "--journal", str(journal),
-    ])
-    assert rc == 0
-    rows = [
-        json.loads(ln) for ln in capsys.readouterr().out.splitlines()
-        if ln.startswith("{")
-    ]
-    by_metric = {r["metric"]: r for r in rows}
-    uni = by_metric["exchange_ring_vs_alltoall_uniform_int32_100000"]
-    zipf = by_metric["exchange_ring_vs_alltoall_zipf_int64_100000"]
-    kv = by_metric["exchange_ring_vs_alltoall_kv_65536_records"]
-    assert kv["unit"] == "rec/sec"
-    for row in (uni, zipf, kv):
-        assert row["bit_identical"] is True
-        assert row["value"] > 0 and row["alltoall_keys_per_sec"] > 0
-        assert row["bytes_on_wire"] > 0
-        assert row["capacity_retries_ring"] == 0
-    # The skewed workload is where the adaptive headroom pays: fewer wire
-    # bytes than the padded path actually shipped.
-    assert zipf["bytes_on_wire"] < zipf["bytes_on_wire_alltoall"]
-    types = [r["type"] for r in EventLog.read_jsonl(str(journal))]
-    assert "exchange_step" in types
+@pytest.mark.parametrize("workload", SCHEDULE_WORKLOADS)
+@pytest.mark.parametrize("exchange", ["alltoall", "ring", "fused"])
+def test_schedule_sorts_workload(mesh8, exchange, workload):
+    """Each exchange schedule returns the reference order on each workload
+    and charges its wire bytes; the lax ring dispatches P-1 steps with no
+    capacity retry, and the fused ring one kernel launch."""
+    n = 20_000
+    m = Metrics()
+    if workload == "kv_records":
+        # The ring schedules take no secondary key, so the records are
+        # ordered by their 8-byte prefix alone: distinct here, so the
+        # reference order is exact.
+        keys, payload = gen_terasort(4096, seed=3)
+        assert len(np.unique(keys)) == len(keys)
+        ss = SampleSort(mesh8, JobConfig(key_dtype=np.uint64,
+                                         payload_bytes=payload.shape[1]))
+        out_k, out_v = ss.sort_kv(keys, payload, metrics=m, exchange=exchange)
+        order = np.argsort(keys)
+        np.testing.assert_array_equal(out_k, keys[order])
+        np.testing.assert_array_equal(out_v, payload[order])
+    else:
+        dtype = np.int64 if workload.endswith("int64") else np.int32
+        data = (gen_zipf(n, a=1.3, dtype=dtype, seed=4)
+                if workload.startswith("zipf") else gen_uniform(n, dtype, seed=0))
+        ss = SampleSort(mesh8, JobConfig(key_dtype=dtype))
+        np.testing.assert_array_equal(
+            ss.sort(data, metrics=m, exchange=exchange), np.sort(data)
+        )
+    assert m.counters["exchange_bytes_on_wire"] > 0
+    p = mesh8.shape["w"]
+    if exchange == "ring":
+        assert m.counters.get("capacity_retries", 0) == 0
+        ring_steps = dispatches_per_exchange("ring", p)
+        assert m.counters["exchange_ring_steps"] == ring_steps == p - 1
+    if exchange == "fused":
+        launches = dispatches_per_exchange("fused", p)
+        assert m.counters["fused_exchange_launches"] == launches == 1
 
 
 def test_cli_run_with_ring_exchange(tmp_path):

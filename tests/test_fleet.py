@@ -4,7 +4,7 @@ size routing over live agents, draining and agent-loss re-routing, the
 typed ``no_capacity`` verdict, the controller-restart drill (zero jobs
 lost or re-dispatched, journal-asserted), the fleet observability
 satellites (`dsort top` multi-URL, `dsort report` directory/glob), and
-the `dsort bench --fleet-mixed` gate + BENCH_r12 artifact."""
+a mixed three-tenant workload over two mesh-owning agents."""
 
 import json
 import os
@@ -27,6 +27,8 @@ from dsort_tpu.serve.policy import ControlPolicy
 from dsort_tpu.utils.events import COUNTERS, EVENT_TYPES, EventLog
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+pytestmark = pytest.mark.usefixtures("fresh_compile_ledger")
 
 
 def _sort_runner(data, metrics, job_id=None):
@@ -934,66 +936,102 @@ def test_cli_fleet_agent_process_drains_on_sigterm(tmp_path):
         == {"acme"}
 
 
-# -- bench gate + artifact ---------------------------------------------------
+# -- mixed tenants over two mesh-owning agents ------------------------------
 
 
-def test_bench_fleet_mixed_gate(capsys):
-    """Tier-1 gate for `make fleet-smoke`: 2 real agents over TCP behind
-    the controller, locality beating random on the fleet-wide variant-
-    cache hit rate, bit-identical outputs — and (ISSUE 14) the health
-    arm's own row with live verdict counts plus the measured
-    telemetry-vs-heartbeats-only overhead on the locality row."""
-    from dsort_tpu import cli
+def _fleet_mixed(routing):
+    """Three tenants' small jobs at two repeat sizes, twice over, plus one
+    full-mesh job, through two agents that each own half the mesh.
 
-    rc = cli.main(["bench", "--fleet-mixed", "--n", "20000", "--reps", "1"])
-    out = capsys.readouterr().out
-    rows = {
-        r["metric"]: r for r in (
-            json.loads(ln) for ln in out.splitlines() if ln.startswith("{")
+    The first job of each size goes out as a pair, so each size gets its
+    home agent; once both are compiled and advertised, the rest go out
+    as one burst, all submitted before any result is awaited.  Returns
+    whether every output equals ``np.sort``, the fleet-wide variant-cache
+    hit rate, the agent each job was routed to, and the rerouted and
+    health-verdict counts."""
+    import jax
+
+    from dsort_tpu.config import ServeConfig
+    from dsort_tpu.serve import SortService
+
+    devs = jax.devices()[:8]
+    serve = ServeConfig(max_queue_depth=64, max_tenant_inflight=32)
+    agents = [
+        FleetAgent(service=SortService(devices=half, serve=serve),
+                   agent_id=f"{routing}-{i}")
+        for i, half in enumerate((devs[:4], devs[4:]))
+    ]
+    journal = EventLog()
+    ctl = FleetController(
+        [a.addr for a in agents], routing=routing, heartbeat_s=0.2,
+        journal=journal, max_queue_depth=64, max_tenant_inflight=32,
+    )
+    sizes = (20_000, 10_000)
+    rng = np.random.default_rng(0)
+    jobs = [
+        (t, rng.integers(0, 1 << 30, sizes[j % 2], dtype=np.int32))
+        for _ in range(2) for j in range(4) for t in ("acme", "blue", "coral")
+    ]
+    jobs.append(("acme", rng.integers(0, 1 << 30, 1 << 20, dtype=np.int32)))
+    first = [jobs[0], jobs[3]]  # the first job of each size
+    burst = [job for i, job in enumerate(jobs) if i not in (0, 3)]
+
+    def run(batch):
+        tickets = [ctl.submit(d, tenant=t)[1] for t, d in batch]
+        return all(
+            np.array_equal(ticket.result(timeout=300), np.sort(d))
+            for (_, d), ticket in zip(batch, tickets)
         )
-    }
-    assert rc == 0
-    row = rows["fleet_mixed_workload_2agents"]
-    assert row["unit"] == "jobs/sec" and row["value"] > 0
-    assert row["bit_identical"] is True
-    assert row["agents"] == 2 and row["jobs"] >= 13
-    assert row["cache_hit_rate"] > row["cache_hit_rate_random"]
-    assert row["fairness_p95_ratio"] > 0
-    assert row["rerouted"] == 0
-    # Overhead is recorded at this scale, gated (<5%) on the real-scale
-    # artifact (BENCH_r14.jsonl) where timing is not noise-dominated.
-    assert isinstance(row["telemetry_overhead_frac"], float)
-    health = rows["fleet_mixed_health_routing_2agents"]
-    assert health["unit"] == "jobs/sec" and health["value"] > 0
-    assert health["bit_identical"] is True
-    assert health["health_verdicts"] > 0
-    assert health["cache_hit_rate"] >= 0
+
+    try:
+        identical = run(first)
+        rungs = {proto.fused_rung_prefix(n, "int32") for n in sizes}
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline and not all(
+            any(v.startswith(r) for a in ctl.agent_info() for v in a["variants"])
+            for r in rungs
+        ):
+            time.sleep(0.05)
+        identical = run(burst) and identical
+        stats = [a.service.variants.stats() for a in agents]
+        hits = sum(st["hits"] for st in stats)
+        lookups = hits + sum(st["misses"] for st in stats)
+        events = journal.events()
+        routed = [e.fields["agent"] for e in events if e.type == "job_routed"]
+        types = [e.type for e in events]
+        return (identical, hits / lookups, routed,
+                types.count("job_rerouted"), types.count("health_verdict"))
+    finally:
+        _close_all(ctl, agents)
 
 
-def test_bench_r12_artifact_checks_and_compares():
-    """BENCH_r12.jsonl: --check clean, the fleet row joins the trajectory
-    as 'added' vs r11, and the recorded row carries the acceptance
-    contract: locality > random hit rate, bit_identical, fairness inside
-    the PR 7 3x bound."""
-    import importlib.util
+@pytest.fixture(scope="module")
+def random_routing_hit_rate():
+    """The hit rate random placement reaches on the same jobs: the
+    baseline the locality case must beat."""
+    from dsort_tpu.obs.prof import LEDGER
 
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    r12 = os.path.join(REPO, "BENCH_r12.jsonl")
-    assert bench.check_artifact(r12) == []
-    rows = bench.compare_artifacts(os.path.join(REPO, "BENCH_r11.jsonl"), r12)
-    added = {r["metric"] for r in rows if r["class"] == "added"}
-    assert any(m.startswith("fleet_mixed_workload") for m in added)
-    with open(r12) as f:
-        lines = [json.loads(ln) for ln in f if ln.strip()]
-    row = next(
-        l for l in lines
-        if l.get("metric", "").startswith("fleet_mixed_workload")
-    )
-    assert row["bit_identical"] is True
-    assert row["cache_hit_rate"] > row["cache_hit_rate_random"]
-    assert row["fairness_p95_ratio"] <= 3.0
-    assert row["agents"] == 2
+    LEDGER.reset()
+    identical, hit_rate, _, rerouted, _ = _fleet_mixed("random")
+    assert identical and rerouted == 0
+    LEDGER.reset()
+    return hit_rate
+
+
+@pytest.mark.parametrize("routing", ["locality", "health"])
+def test_fleet_mixed_tenants_two_agents(routing, request):
+    """Both routings return every job's sorted keys with nothing rerouted.
+    The first pair splits over the two agents, because a job routed but
+    not yet accepted counts as load.  Locality beats random placement on
+    the fleet-wide variant-cache hit rate, and the health routing folds
+    live telemetry into verdicts."""
+    baseline = (request.getfixturevalue("random_routing_hit_rate")
+                if routing == "locality" else None)
+    identical, hit_rate, routed, rerouted, verdicts = _fleet_mixed(routing)
+    assert identical
+    assert rerouted == 0
+    assert len(set(routed[:2])) == 2, routed
+    if routing == "locality":
+        assert hit_rate > baseline
+    else:
+        assert verdicts > 0

@@ -3,10 +3,9 @@ bounded telemetry delta stream over the fleet protocol, the streaming
 why-slow analyzer (`obs.health`) and its live==replay contract against
 `obs.analyze`, health-aware big-job routing (drilled A/B vs locality),
 the degraded->flight-bundle contract, per-agent health gauges + the
-`dsort top` health pane, protocol-level clock sync for `dsort report
---merge`, and the `bench.py --history` trajectory satellite."""
+`dsort top` health pane, and protocol-level clock sync for `dsort report
+--merge`."""
 
-import json
 import os
 import time
 
@@ -29,6 +28,8 @@ from dsort_tpu.utils.events import COUNTERS, EVENT_TYPES, EventLog
 from dsort_tpu.utils.metrics import Metrics, PhaseTimer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+pytestmark = pytest.mark.usefixtures("fresh_compile_ledger")
 
 
 def _slow_runner(data, metrics, job_id=None):
@@ -638,109 +639,3 @@ def test_fleet_cli_accepts_health_routing():
     assert FleetConfig(routing="health").routing == "health"
     with pytest.raises(ConfigError, match="routing"):
         FleetConfig(routing="mystery")
-
-
-# -- bench.py --history (satellite) ------------------------------------------
-
-
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
-
-def test_bench_history_consolidates_real_artifacts():
-    """Tier-1 gate on the REAL in-tree artifacts: the trajectory table
-    covers every BENCH_r*.jsonl, steps classify on the --compare ladder,
-    and the fleet rows appear where their PRs recorded them."""
-    bench = _load_bench()
-    hist = bench.history_rows(REPO)
-    names = hist["artifacts"]
-    assert "BENCH_r12.jsonl" in names and "BENCH_r14.jsonl" in names
-    assert names == sorted(
-        names, key=lambda n: int(n.split("_r")[1].split("_")[0].split(".")[0])
-    )
-    fleet_metric = "fleet_mixed_workload_2agents_8dev_cpu_mesh"
-    fleet = hist["metrics"][fleet_metric]
-    assert "BENCH_r12.jsonl" in fleet and "BENCH_r14.jsonl" in fleet
-    health = hist["metrics"]["fleet_mixed_health_routing_2agents_8dev_cpu_mesh"]
-    assert set(health) == {"BENCH_r14.jsonl"}
-    valid = {"ok", "noise", "regression", "severe", "info"}
-    for metric, steps in hist["steps"].items():
-        for s in steps:
-            assert s["class"] in valid, (metric, s)
-    # The r12 -> r14 fleet step joined the trajectory (jobs/sec is not a
-    # rate unit on the ladder, so it reports info, never a false alarm).
-    fleet_steps = [
-        s for s in hist["steps"][fleet_metric]
-        if s["to"] == "BENCH_r14.jsonl"
-    ]
-    assert fleet_steps and fleet_steps[0]["class"] == "info"
-    # Rate metrics DO classify on the ladder with a ratio per step.
-    rated = [
-        s for metric, steps in hist["steps"].items()
-        for s in steps
-        if hist["metrics"][metric][s["to"]].get("unit") == "keys/sec"
-    ]
-    assert rated and all("ratio" in s for s in rated)
-
-
-def test_bench_history_cli(tmp_path):
-    import subprocess
-    import sys
-
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--history", REPO],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr
-    assert "fleet_mixed_workload_2agents" in r.stdout
-    assert '"metric": "history_summary"' in r.stdout
-    empty = tmp_path / "none"
-    empty.mkdir()
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--history",
-         str(empty)],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert r.returncode == 2
-
-
-# -- BENCH_r14 artifact (acceptance) -----------------------------------------
-
-
-def test_bench_r14_artifact_checks_and_compares():
-    """BENCH_r14.jsonl: --check clean, the health row joins the
-    trajectory as 'added' vs r12, the fleet row still carries the
-    locality>random contract, and the live-telemetry overhead on the
-    fleet-mixed bench is < 5% vs heartbeats-only."""
-    bench = _load_bench()
-    r14 = os.path.join(REPO, "BENCH_r14.jsonl")
-    assert bench.check_artifact(r14) == []
-    rows = bench.compare_artifacts(os.path.join(REPO, "BENCH_r12.jsonl"), r14)
-    added = {r["metric"] for r in rows if r["class"] == "added"}
-    assert any(
-        m.startswith("fleet_mixed_health_routing") for m in added
-    )
-    with open(r14) as f:
-        lines = [json.loads(ln) for ln in f if ln.strip()]
-    fleet = next(
-        l for l in lines
-        if l.get("metric", "").startswith("fleet_mixed_workload")
-    )
-    assert fleet["bit_identical"] is True
-    assert fleet["cache_hit_rate"] > fleet["cache_hit_rate_random"]
-    assert fleet["fairness_p95_ratio"] <= 3.0
-    assert fleet["telemetry_overhead_frac"] < 0.05
-    health = next(
-        l for l in lines
-        if l.get("metric", "").startswith("fleet_mixed_health_routing")
-    )
-    assert health["bit_identical"] is True
-    assert health["health_verdicts"] > 0
-    assert health["value"] > 0

@@ -5,7 +5,7 @@ torn-line tolerance, multi-lane Chrome export), the live metrics endpoint
 (Prometheus render + minimal-parser round trip + HTTP scrape), the
 per-tenant SLO histograms (live tap == journal replay, exactly), and the
 fault flight recorder (bundle schema + one drill per recovery path).  The
-serve-smoke gate at the bottom is the acceptance path: `dsort serve
+serve metrics-endpoint test is the acceptance path: `dsort serve
 --metrics-port` scraped mid-session, quantiles asserted against the
 journal-derived ground truth.
 """

@@ -8,6 +8,7 @@ the planner vanish bit-identically; and `obs.analyze`'s ``plan`` verdict
 replays every decision from the journal alone with zero mismatches.
 """
 
+import dataclasses
 import json
 import os
 
@@ -292,32 +293,37 @@ def test_prewarm_history_is_bounded():
 # ---- the sample_sort seam (mesh) -------------------------------------------
 
 
-def test_autotune_picks_ring_on_zipf_alltoall_on_uniform(mesh8):
+@pytest.mark.parametrize("workload,expected", [
+    ("zipf_int64", "ring"), ("uniform_int32", "alltoall"),
+])
+def test_autotune_picks_ring_on_zipf_alltoall_on_uniform(mesh8, workload,
+                                                        expected):
     """The exchange policy end to end: the planner's measured probe picks
-    ring for the skewed workload and alltoall for the uniform one, each
+    ring for the skewed workload and alltoall for the uniform one, the
     dispatch journals ONE plan_decision, and the sorted output is
-    bit-identical to the unplanned path."""
+    bit-identical to the unplanned path under either hand-set schedule."""
     from dsort_tpu.parallel.sample_sort import SampleSort
 
-    zipf = gen_zipf(1 << 17, a=1.3, seed=4)
-    uni = gen_uniform(1 << 17, seed=0)
+    if workload == "zipf_int64":
+        keys = gen_zipf(1 << 17, a=1.3, seed=4)
+        job = JobConfig(key_dtype=np.int64)
+    else:
+        keys, job = gen_uniform(1 << 17, seed=0), JobConfig()
     m = _metered()
-    auto64 = SampleSort(mesh8, JobConfig(autotune=True, key_dtype=np.int64))
-    out_z = auto64.sort(zipf, metrics=m)
-    auto32 = SampleSort(mesh8, JobConfig(autotune=True))
-    out_u = auto32.sort(uni, metrics=m)
+    auto = SampleSort(mesh8, dataclasses.replace(job, autotune=True))
+    out = auto.sort(keys, metrics=m)
     plans = [e for e in m.journal.events() if e.type == "plan_decision"]
-    assert [p.fields["policy"] for p in plans] == ["exchange", "exchange"]
-    assert plans[0].fields["chosen"] == "ring"
-    assert plans[1].fields["chosen"] == "alltoall"
+    assert [p.fields["policy"] for p in plans] == ["exchange"]
+    assert plans[0].fields["chosen"] == expected
     # the decision's measured input is the probe of THIS job's keys
-    assert plans[0].fields["inputs"]["max_mean_ratio"] >= SKEW_RING_THRESHOLD
-    assert plans[1].fields["inputs"]["max_mean_ratio"] < SKEW_RING_THRESHOLD
-    np.testing.assert_array_equal(out_z, np.sort(zipf))
-    np.testing.assert_array_equal(out_u, np.sort(uni))
-    # bit-identical to the unplanned path (--no-autotune A/B)
-    plain = SampleSort(mesh8, JobConfig(key_dtype=np.int64))
-    np.testing.assert_array_equal(out_z, plain.sort(zipf))
+    skewed = plans[0].fields["inputs"]["max_mean_ratio"] >= SKEW_RING_THRESHOLD
+    assert skewed == (expected == "ring")
+    np.testing.assert_array_equal(out, np.sort(keys))
+    # bit-identical to the unplanned path (--no-autotune A/B), whichever
+    # schedule is set by hand
+    plain = SampleSort(mesh8, job)
+    for exchange in ("alltoall", "ring"):
+        np.testing.assert_array_equal(out, plain.sort(keys, exchange=exchange))
 
 
 def test_autotune_per_call_exchange_journals_override(mesh8):
@@ -671,7 +677,7 @@ def test_plan_table_renders_lists_and_empty():
     assert "[3 key(s)]" in txt
 
 
-# ---- CLI A/B + bench gates --------------------------------------------------
+# ---- CLI A/B ---------------------------------------------------------------
 
 
 def test_cli_no_autotune_ab_bit_identical(tmp_path):
@@ -704,68 +710,6 @@ def test_cli_no_autotune_ab_bit_identical(tmp_path):
     assert v["mismatches"] == 0
 
 
-def test_cli_bench_autotune_ab_gate(capsys):
-    """Tier-1 gate for `make autotune-smoke`: the A/B harness runs end to
-    end — planner picks ring on zipf / alltoall on uniform, outputs
-    bit-identical to both hand-set arms, one plan_decision per rep."""
-    from dsort_tpu import cli
-
-    rc = cli.main(["bench", "--autotune-ab", "--n", "65536", "--reps", "1"])
-    out = capsys.readouterr().out
-    rows = [json.loads(ln) for ln in out.splitlines() if ln.startswith("{")]
-    assert rc == 0
-    assert len(rows) == 2
-    zipf = next(r for r in rows if "zipf" in r["metric"])
-    uni = next(r for r in rows if "uniform" in r["metric"])
-    assert zipf["chosen_exchange"] == "ring"
-    assert uni["chosen_exchange"] == "alltoall"
-    for r in rows:
-        assert r["bit_identical"] is True
-        assert r["plan_decisions"] == 1
-        assert r["autotune_vs_best"] > 0
-        assert r["alltoall_keys_per_sec"] > 0
-        assert r["ring_keys_per_sec"] > 0
-
-
-def test_cli_bench_autotune_ab_is_exclusive():
-    from dsort_tpu import cli
-
-    with pytest.raises(SystemExit, match="its own benchmark"):
-        cli.main(["bench", "--autotune-ab", "--suite"])
-
-
-def test_bench_r16_artifact_checks_and_compares():
-    """BENCH_r16.jsonl: --check clean, the autotune rows join the
-    trajectory as 'added' vs r15, and the headline holds: the planner
-    picked the right schedule per workload, bit-identically, at >= 0.95x
-    the best hand-set arm."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    r16 = os.path.join(REPO, "BENCH_r16.jsonl")
-    assert bench.check_artifact(r16) == []
-    rows = bench.compare_artifacts(os.path.join(REPO, "BENCH_r15.jsonl"), r16)
-    added = {r["metric"] for r in rows if r["class"] == "added"}
-    assert any(m.startswith("autotune_ab_zipf") for m in added)
-    assert any(m.startswith("autotune_ab_uniform") for m in added)
-    with open(r16) as f:
-        lines = [json.loads(ln) for ln in f if ln.strip()]
-    zipf = next(l for l in lines
-                if l.get("metric", "").startswith("autotune_ab_zipf"))
-    uni = next(l for l in lines
-               if l.get("metric", "").startswith("autotune_ab_uniform"))
-    assert zipf["chosen_exchange"] == "ring"
-    assert uni["chosen_exchange"] == "alltoall"
-    for l in (zipf, uni):
-        assert l["bit_identical"] is True
-        assert l["autotune_vs_best"] >= 0.95  # the planner paid for itself
-        assert l["plan_decisions"] >= 1
-
-
 # ---- docs are part of the contract ------------------------------------------
 
 
@@ -786,5 +730,5 @@ def test_architecture_documents_planner_plane():
                  "REDUNDANCY_DEGRADED_FRAC", "PREWARM_HISTORY",
                  "replay_decision", "--no-autotune", "AUTOTUNE",
                  "explicit flag > conf file > planner",
-                 "--prewarm all", "autotune-smoke"):
+                 "--prewarm all"):
         assert term in arch, f"§15 must explain {term}"

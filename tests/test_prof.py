@@ -6,7 +6,8 @@ carried), skew & straggler attribution (skew_report fields, memwatch
 watermarks), the journal-native analyzer (merged 2-process ground truth
 with an injected-latency straggler; a REAL in-suite serve session), SLO
 admission shedding with recovery, journal rotation + report stitching,
-the analyze-smoke gate, and the §9 schema enforcement.
+the analyzer's verdict on a real ring sort, and the §9 schema
+enforcement.
 """
 
 import json
@@ -505,51 +506,49 @@ def test_slo_shed_config_validation_and_conf_key():
     assert "slo_shed" in ADMISSION_REASONS
 
 
-# -- the analyze-smoke gate (satellite: make profile-smoke) ------------------
+# -- the analyzer on a real ring sort -----------------------------------------
 
 
-def test_bench_analyze_smoke_gate(capsys, devices):
-    """Tier-1 gate for `make profile-smoke`: the introspection-plane cost
-    harness runs end to end on the in-suite mesh — skew margin real,
-    analyzer verdict coherent.  (The < 5% overhead contract binds at the
-    1M row recorded in BENCH_r09.jsonl; at this gate's small n the
-    timing is noise-dominated and only sanity-bounded.)"""
-    from dsort_tpu import cli
+def _analyzed_ring_sort(dist):
+    """A ring sort of 2^17 int64 keys with the journal and the memwatch
+    tap attached, and the analyzer's verdict on that journal."""
+    from dsort_tpu.config import JobConfig
+    from dsort_tpu.data.ingest import gen_uniform, gen_zipf
+    from dsort_tpu.parallel.mesh import local_device_mesh
+    from dsort_tpu.parallel.sample_sort import SampleSort
 
-    rc = cli.main(["bench", "--analyze-smoke", "--n", "200000", "--reps", "2"])
-    out = capsys.readouterr().out
-    row = json.loads(
-        [ln for ln in out.splitlines() if ln.startswith("{")][-1]
-    )
-    assert rc == 0
-    assert row["unit"] == "frac"
-    assert row["introspection_ok"] is True
-    assert row["skew_ratio_zipf"] > row["skew_ratio_uniform"] >= 1.0
-    assert row["dominant_phase"] == "spmd_sort"
-    assert row["bare_keys_per_sec"] > 0 and row["journaled_keys_per_sec"] > 0
-    assert row["hbm_watermark_bytes"] > 0
+    n = 1 << 17
+    data = (gen_zipf(n, a=1.3, seed=4) if dist == "zipf"
+            else gen_uniform(n, dtype=np.int64, seed=0))
+    m = Metrics(journal=EventLog())
+    MemWatch().attach(m)
+    ss = SampleSort(local_device_mesh(8), JobConfig(key_dtype=np.int64))
+    out = ss.sort(data, metrics=m, exchange="ring")
+    np.testing.assert_array_equal(out, np.sort(data))
+    return analyze_records([e.to_dict() for e in m.journal.events()])
 
 
-def test_bench_r09_artifact_checks_and_compares():
-    """BENCH_r09.jsonl: --check clean, and the introspection row joins the
-    trajectory as an 'added' metric vs r07."""
-    import importlib.util
+@pytest.fixture(scope="module")
+def uniform_skew_ratio():
+    """The skew ratio the analyzer reads on uniform keys: the floor zipf
+    keys must rise above."""
+    return _analyzed_ring_sort("uniform")["skew"]["max_mean_ratio"]
 
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    r09 = os.path.join(REPO, "BENCH_r09.jsonl")
-    assert bench.check_artifact(r09) == []
-    rows = bench.compare_artifacts(os.path.join(REPO, "BENCH_r07.jsonl"), r09)
-    added = {r["metric"] for r in rows if r["class"] == "added"}
-    assert "analyze_overhead_1M_8dev_cpu_mesh" in added
-    with open(r09) as f:
-        lines = [json.loads(ln) for ln in f if ln.strip()]
-    row = [l for l in lines if l.get("metric", "").startswith("analyze_")][0]
-    assert row["overhead_frac"] < 0.05 and row["introspection_ok"] is True
-    assert row["skew_ratio_zipf"] > 1.5 * row["skew_ratio_uniform"]
+
+@pytest.mark.parametrize("dist", ["uniform", "zipf"])
+def test_analyzer_reads_skew_phase_and_hbm_of_a_ring_sort(devices, dist,
+                                                          request):
+    """The verdict on a real sort's journal names the device program as the
+    dominant phase and a nonzero HBM watermark; uniform keys read a skew
+    ratio of at least 1 and zipf keys a higher one."""
+    verdict = _analyzed_ring_sort(dist)
+    assert verdict["dominant_phase"] == "spmd_sort"
+    assert verdict["hbm"]["bytes_in_use"] > 0
+    skew = verdict["skew"]["max_mean_ratio"]
+    if dist == "uniform":
+        assert skew >= 1.0
+    else:
+        assert skew > request.getfixturevalue("uniform_skew_ratio")
 
 
 # -- ARCHITECTURE §9 schema enforcement --------------------------------------

@@ -699,3 +699,45 @@ def test_kv_programs_return_the_payload_flat(mesh8, kind):
     name = "_ring_exchange_kv_shard" if kind == "spmd_ring_kv" else (
         "_sample_sort_kv2_shard" if kind == "spmd_kv2" else "_sample_sort_kv_shard")
     assert f"@jit_{name}" in lowered.as_text()
+
+
+# ---- BASELINE.md's config ladder -------------------------------------------
+
+
+@pytest.mark.parametrize("config", [1, 2, 3, 4, 5])
+def test_baseline_config_ladder(mesh8, config):
+    """BASELINE.md's five configs at the sizes the CPU mesh runs them: the
+    reference's own 16,384-key job as one fused program, 2^20 int32 and
+    int64 keys, 65,536 TeraSort records by their full 10-byte key, and
+    2^20 zipf keys with worker 2 lost and the mesh re-formed."""
+    if config == 1:
+        from dsort_tpu.models.pipelines import fused_sort_small
+
+        data = gen_uniform(16_384, seed=0)
+        np.testing.assert_array_equal(fused_sort_small(data), np.sort(data))
+    elif config in (2, 3):
+        dtype = np.int32 if config == 2 else np.int64
+        data = gen_uniform(1 << 20, dtype=dtype, seed=config - 1)
+        out = SampleSort(mesh8, JobConfig(key_dtype=dtype)).sort(data)
+        np.testing.assert_array_equal(out, np.sort(data))
+    elif config == 4:
+        from dsort_tpu.data.ingest import terasort_secondary
+
+        keys, payload = gen_terasort(1 << 16, seed=3)
+        sec = terasort_secondary(payload)
+        ss = SampleSort(mesh8, JobConfig(key_dtype=np.uint64,
+                                         payload_bytes=payload.shape[1]))
+        out_k, out_v = ss.sort_kv(keys, payload, secondary=sec)
+        order = np.lexsort((sec, keys))
+        np.testing.assert_array_equal(out_k, keys[order])
+        np.testing.assert_array_equal(out_v, payload[order])
+    else:
+        from dsort_tpu.scheduler import FaultInjector, SpmdScheduler
+
+        data = gen_zipf(1 << 20, a=1.3, seed=4)
+        inj = FaultInjector()
+        sched = SpmdScheduler(job=JobConfig(settle_delay_s=0.01), injector=inj)
+        inj.fail_once(2, "spmd")
+        m = Metrics()
+        np.testing.assert_array_equal(sched.sort(data, metrics=m), np.sort(data))
+        assert m.counters["mesh_reforms"] >= 1
